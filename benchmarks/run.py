@@ -23,11 +23,9 @@ def main() -> None:
 
     # persistent compilation cache: the FL round programs are large
     # (unrolled S x U bodies) and identical across benchmark reruns
-    import jax
+    from repro.compile_cache import enable_compile_cache
 
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    enable_compile_cache()
 
     # imports AFTER env so benchmarks.common picks the flags up
     from benchmarks import (

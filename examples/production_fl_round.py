@@ -1,7 +1,9 @@
 """Production-regime FL round on a multi-device mesh (runs on CPU host
 devices; the same code drives the 512-chip dry-run).
 
-Spawns itself with XLA_FLAGS so the demo works from a plain shell:
+Spawns itself with XLA_FLAGS so the demo works from a plain shell.  CPU
+only: a parent that has touched JAX holds the accelerator, so it must
+never spawn children that need the chip.
 
     PYTHONPATH=src python examples/production_fl_round.py --arch qwen2.5-14b
 """

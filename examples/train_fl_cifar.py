@@ -123,6 +123,7 @@ def main() -> None:
         print(f"round {m['round']:4d}  acc={m['accuracy']:.4f}", flush=True)
 
     hist = compile(spec).run(progress=progress)
+    hist.pop("params")
     with open(os.path.join(args.out, name + ".json"), "w") as f:
         json.dump({"spec": spec.to_dict(), "history": hist}, f, indent=2)
     print(f"final accuracy: {hist['final_accuracy']:.4f} -> {args.out}/{name}.json")

@@ -74,7 +74,8 @@ def run_experiment(
     progress: Callable[[dict], None] | None = None,
     check: bool = True,  # False: spec already validated (api.compile)
 ) -> dict:
-    """Runs the experiment; returns {round, accuracy, loss, ...} history."""
+    """Runs the experiment; returns the history: accuracy and update norm
+    per eval point, ``final_accuracy``, and the final ``params`` pytree."""
     from repro.api import lowering
     from repro.api.validation import ensure_executable, validate
     from repro.data.pipeline import build_federated_data
@@ -176,6 +177,7 @@ def run_experiment(
                     progress({"round": t + 1, "accuracy": acc, **{k: float(v) for k, v in metrics.items()}})
 
     history["final_accuracy"] = history["accuracy"][-1] if history["accuracy"] else 0.0
+    history["params"] = state.params
     if session.enabled:
         history["telemetry"] = session.summary()
     return history

@@ -33,6 +33,16 @@ scale, blend).  Two kernels bring that to two passes:
 Block sizes default to (8, 1024): G tile 8x1024xf32 = 32 KiB VMEM, r
 tile 4 KiB — well inside the ~16 MiB VMEM budget, lane-dim 1024 is a
 multiple of 128 for clean vectorisation.
+
+Mosaic layout rules the kernels follow: per-worker vectors (dots, norms,
+blend coefficients, weights) travel as ``[S, 1]`` columns, so their
+blocks tile like G's rows; lane vectors (r, Delta) stay rank 1 with
+128-multiple blocks.  No product is rank-1 x rank-2, which Mosaic does
+not lower: per-row dots are an elementwise multiply plus a lane
+reduction, and the weighted row sums are a 2-D contraction
+(:func:`row_weighted_sum`) at ``HIGHEST`` precision, so the MXU keeps
+f32 operands instead of rounding them to one bf16 pass.  The public
+signatures keep ``[S]`` vectors.
 """
 from __future__ import annotations
 
@@ -46,6 +56,27 @@ from repro.kernels.ref import calibrate_coeffs
 
 DEF_BS = 8  # workers per tile (sublane dim)
 DEF_BD = 1024  # parameter-dim tile (lane dim, multiple of 128)
+
+
+def column(x):
+    """[S] -> [S, 1] f32: the Mosaic-tileable per-worker layout."""
+    return jnp.asarray(x, jnp.float32).reshape(-1, 1)
+
+
+def row_weighted_sum(w_col, g):
+    """sum_s w[s] * g[s] for a ``[bs, 1]`` weight column and a
+    ``[bs, bd]`` tile -> ``[bd]`` f32."""
+    return jax.lax.dot_general(
+        w_col, g, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )[0]
+
+
+def row_block(bs: int, row_axis: int):
+    """[bs, 1] block of a per-worker column; the row-tile index is grid
+    axis ``row_axis``."""
+    return pl.BlockSpec((bs, 1), lambda *ij: (ij[row_axis], 0))
 
 
 # ------------------------------------------------------------ dot_norms
@@ -64,12 +95,12 @@ def _dot_norms_kernel(g_ref, r_ref, dots_ref, gsq_ref, rsq_ref):
 
     g = g_ref[...].astype(jnp.float32)  # [bs, bd]
     r = r_ref[...].astype(jnp.float32)  # [bd]
-    dots_ref[...] += g @ r
-    gsq_ref[...] += jnp.sum(g * g, axis=1)
+    dots_ref[...] += jnp.sum(g * r[None, :], axis=1, keepdims=True)
+    gsq_ref[...] += jnp.sum(g * g, axis=1, keepdims=True)
     # accumulate ||r||^2 once per d-tile (only on the first worker row)
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(i == 0)
     def _racc():
-        rsq_ref[...] += jnp.sum(r * r)[None]
+        rsq_ref[...] += jnp.sum(r * r)
 
 
 def dot_norms(g, r, *, block_s: int = DEF_BS, block_d: int = DEF_BD, interpret: bool = False):
@@ -85,18 +116,18 @@ def dot_norms(g, r, *, block_s: int = DEF_BS, block_d: int = DEF_BD, interpret: 
             pl.BlockSpec((bd,), lambda i, j: (j,)),
         ],
         out_specs=[
-            pl.BlockSpec((bs,), lambda i, j: (i,)),
-            pl.BlockSpec((bs,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            row_block(bs, 0),
+            row_block(bs, 0),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((s,), jnp.float32),
-            jax.ShapeDtypeStruct((s,), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(g, r)
-    return dots, gsq, rsq[0]
+    return dots[:, 0], gsq[:, 0], rsq[0, 0]
 
 
 # ---------------------------------------------------------------- blend
@@ -104,9 +135,7 @@ def dot_norms(g, r, *, block_s: int = DEF_BS, block_d: int = DEF_BD, interpret: 
 def _blend_kernel(g_ref, r_ref, a_ref, b_ref, v_ref):
     g = g_ref[...].astype(jnp.float32)
     r = r_ref[...].astype(jnp.float32)
-    a = a_ref[...][:, None]
-    b = b_ref[...][:, None]
-    v_ref[...] = (a * g + b * r[None, :]).astype(v_ref.dtype)
+    v_ref[...] = (a_ref[...] * g + b_ref[...] * r[None, :]).astype(v_ref.dtype)
 
 
 def blend(g, r, a, b, *, block_s: int = DEF_BS, block_d: int = DEF_BD, interpret: bool = False):
@@ -120,13 +149,13 @@ def blend(g, r, a, b, *, block_s: int = DEF_BS, block_d: int = DEF_BD, interpret
         in_specs=[
             pl.BlockSpec((bs, bd), lambda i, j: (i, j)),
             pl.BlockSpec((bd,), lambda i, j: (j,)),
-            pl.BlockSpec((bs,), lambda i, j: (i,)),
-            pl.BlockSpec((bs,), lambda i, j: (i,)),
+            row_block(bs, 0),
+            row_block(bs, 0),
         ],
         out_specs=pl.BlockSpec((bs, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((s, d), g.dtype),
         interpret=interpret,
-    )(g, r, a, b)
+    )(g, r, column(a), column(b))
 
 
 # --------------------------------------------------------- blend_reduce
@@ -140,11 +169,11 @@ def _blend_reduce_kernel(g_ref, r_ref, aw_ref, bw_ref, out_ref):
 
     g = g_ref[...].astype(jnp.float32)  # [bs, bd]
     r = r_ref[...].astype(jnp.float32)  # [bd]
-    aw = aw_ref[...].astype(jnp.float32)  # [bs]
-    bw = bw_ref[...].astype(jnp.float32)  # [bs]
+    aw = aw_ref[...]  # [bs, 1]
+    bw = bw_ref[...]  # [bs, 1]
     # sum_s aw_s g_s + (sum_s bw_s) r, accumulated per worker tile; the
     # [bd] output block stays VMEM-resident across the inner i loop
-    out_ref[...] += aw @ g + jnp.sum(bw) * r
+    out_ref[...] += row_weighted_sum(aw, g) + jnp.sum(bw) * r
 
 
 def blend_reduce(g, r, aw, bw, *, block_s: int = DEF_BS, block_d: int = DEF_BD,
@@ -166,13 +195,13 @@ def blend_reduce(g, r, aw, bw, *, block_s: int = DEF_BS, block_d: int = DEF_BD,
         in_specs=[
             pl.BlockSpec((bs, bd), lambda j, i: (i, j)),
             pl.BlockSpec((bd,), lambda j, i: (j,)),
-            pl.BlockSpec((bs,), lambda j, i: (i,)),
-            pl.BlockSpec((bs,), lambda j, i: (i,)),
+            row_block(bs, 1),
+            row_block(bs, 1),
         ],
         out_specs=pl.BlockSpec((bd,), lambda j, i: (j,)),
         out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
         interpret=interpret,
-    )(g, r, aw, bw)
+    )(g, r, column(aw), column(bw))
 
 
 # ---------------------------------------------------------- fused_flush
@@ -184,8 +213,8 @@ def _fused_flush_kernel(g_ref, r_ref, phi_ref, w_ref, u_ref, sel_ref,
     # over it in place of the separate dot_norms pass...
     g = g_ref[...].astype(jnp.float32)  # [S, d]
     r = r_ref[...].astype(jnp.float32)  # [d]
-    dots = g @ r
-    gsq = jnp.sum(g * g, axis=1)
+    dots = jnp.sum(g * r[None, :], axis=1, keepdims=True)  # [S, 1]
+    gsq = jnp.sum(g * g, axis=1, keepdims=True)
     rsq = jnp.sum(r * r)
     # ...and the blend coefficients come straight from the just-reduced
     # scalars — the exact host-side formulas (eqs. (11)/(15)), so the
@@ -195,13 +224,13 @@ def _fused_flush_kernel(g_ref, r_ref, phi_ref, w_ref, u_ref, sel_ref,
         b = jnp.zeros_like(dots)
     else:
         a, b, _ = calibrate_coeffs(dots, gsq, rsq, c, mode, phi_ref[...])
-    sel = sel_ref[0] > 0.5  # DRAG bootstrap switch (eq. 5a)
+    sel = sel_ref[...] > 0.5  # [1, 1] DRAG bootstrap switch (eq. 5a)
     aw = jnp.where(sel, w_ref[...] * a, u_ref[...])
     bw = jnp.where(sel, w_ref[...] * b, 0.0)
-    delta_ref[...] = aw @ g + jnp.sum(bw) * r
+    delta_ref[...] = row_weighted_sum(aw, g) + jnp.sum(bw) * r
     dots_ref[...] = dots
     gsq_ref[...] = gsq
-    rsq_ref[...] = rsq[None]
+    rsq_ref[...] = jnp.full((1, 1), rsq)
 
 
 def fused_flush(g, r, phi, w, u, sel, *, c: float, mode: str,
@@ -209,7 +238,7 @@ def fused_flush(g, r, phi, w, u, sel, *, c: float, mode: str,
     """Single-pass DRAG/BR-DRAG flush for VMEM-resident stacks.
 
     One HBM read of ``G:[S, d]`` produces (delta [d], dots [S], gsq [S],
-    rsq [1]): the phase-1 scalars, the in-kernel coefficients, the
+    rsq []): the phase-1 scalars, the in-kernel coefficients, the
     bootstrap select ``aw = sel ? w*a : u`` / ``bw = sel ? w*b : 0`` and
     the fused weighted reduction.  ``phi`` are staleness discounts
     (ones when fresh), ``w`` the normalised aggregation weights, ``u``
@@ -223,10 +252,10 @@ def fused_flush(g, r, phi, w, u, sel, *, c: float, mode: str,
         functools.partial(_fused_flush_kernel, c=c, mode=mode),
         out_shape=[
             jax.ShapeDtypeStruct((d,), jnp.float32),
-            jax.ShapeDtypeStruct((s,), jnp.float32),
-            jax.ShapeDtypeStruct((s,), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(g, r, phi, w, u, sel)
-    return delta, dots, gsq, rsq[0]
+    )(g, r, column(phi), column(w), column(u), column(sel))
+    return delta, dots[:, 0], gsq[:, 0], rsq[0, 0]

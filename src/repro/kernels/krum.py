@@ -30,8 +30,13 @@ def _gram_kernel(g_ref, gram_ref):
         gram_ref[...] = jnp.zeros_like(gram_ref)
 
     g = g_ref[...].astype(jnp.float32)  # [S, bd]
-    # [S, S] accumulator stays VMEM-resident across the d-grid
-    gram_ref[...] += g @ g.T
+    # [S, S] accumulator stays VMEM-resident across the d-grid; HIGHEST
+    # keeps the MXU from rounding the f32 operands to one bf16 pass
+    gram_ref[...] += jax.lax.dot_general(
+        g, g, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def gram(g, *, block_d: int = DEF_BD, interpret: bool = False):

@@ -99,16 +99,12 @@ def _block_sizes(s: int, d: int) -> tuple[int, int]:
     Callers align first (``_pad_grid``): S to a multiple of 8 once it
     exceeds one sublane tile, d to a lane-aligned multiple — real-TPU
     Mosaic tiling needs lane-dim multiples of 128 and f32 sublane
-    multiples of 8, and an unaligned fallback tile of bd = d would also
-    blow the VMEM budget for large models.
+    multiples of 8 (or the whole axis), and an unaligned fallback tile
+    of bd = d would also blow the VMEM budget for large models.
     """
-    if s % 8 == 0:
-        bs = 8
-    elif s <= 8:
-        bs = s
-    else:  # exact-divisor fallback (Weiszfeld path, which cannot S-pad)
-        bs = 4 if s % 4 == 0 else (2 if s % 2 == 0 else 1)
-    return bs, _lane_block(d) if d % 128 == 0 else d
+    if s > 8 and s % 8:
+        raise ValueError(f"worker axis {s} is not sublane-aligned; pad it first")
+    return min(s, 8), _lane_block(d) if d % 128 == 0 else d
 
 
 # ------------------------------------------------------- autotune cache
@@ -236,7 +232,7 @@ def _select_blocks(op: str, gp, interpret: bool) -> tuple[int, int]:
     return _block_sizes(s, d)
 
 
-def _pad_grid(g, r, pad_s: bool = True):
+def _pad_grid(g, r):
     """Zero-pad G (rows and/or lanes) and r (lanes) to tile-aligned shapes.
 
     Lanes pad to a multiple of 1024 (128 for small d) so ``_lane_block``
@@ -252,7 +248,7 @@ def _pad_grid(g, r, pad_s: bool = True):
     lane_mult = _lane_mult(d)
     g, _ = _pad_to(g, lane_mult, axis=1)
     r, _ = _pad_to(r, lane_mult, axis=0)
-    if pad_s and s > 8:
+    if s > 8:
         g, _ = _pad_to(g, 8, axis=0)
     return g, r, s, d
 
@@ -281,9 +277,12 @@ def flush_path(s: int, d: int) -> str:
     (flat engines, sharded pods, instrumentation, benchmarks) resolves
     through here, so the bit-for-bit oracles stay path-consistent.  With
     autotune on, an eligible shape is measured both ways instead.
+
+    The budget counts at least 8 rows: VMEM tiles f32 in 8-sublane
+    units, so an [S < 8, d] block occupies as much as an [8, d] one.
     """
     s_pad, d_pad = _padded_shape(s, d)
-    if s_pad * d_pad * 4 > FUSED_VMEM_BYTES:
+    if max(s_pad, 8) * d_pad * 4 > FUSED_VMEM_BYTES:
         return "two_pass"
     if _AUTOTUNE:
         return _tuned_path(s, d)
@@ -478,16 +477,20 @@ def drag_calibrate_reduce(
 def geometric_median(g, iters: int = 8, eps: float = 1e-8, interpret: bool | None = None):
     """Weiszfeld iterations over G:[S,d] using the two Pallas kernels."""
     interpret = _interpret_default() if interpret is None else interpret
-    # lane-align only: padded zero COLUMNS stay exactly zero through the
-    # iteration; padded rows would enter the Weiszfeld weights, so the
-    # worker axis keeps its exact-divisor tiling instead
+    # padded zero COLUMNS stay exactly zero through the iteration; padded
+    # zero ROWS would enter the Weiszfeld weights, so their weights are
+    # masked to exactly zero (no share of the numerator or of sum(w))
     gp, d0 = _pad_to(g, _lane_mult(g.shape[1]), axis=1)
-    bs, bd = _select_blocks("weiszfeld", gp, interpret)
     z = jnp.mean(gp.astype(jnp.float32), axis=0)
+    s = g.shape[0]
+    if s > 8:
+        gp, _ = _pad_to(gp, 8, axis=0)
+    live = jnp.arange(gp.shape[0]) < s
+    bs, bd = _select_blocks("weiszfeld", gp, interpret)
 
     def body(z, _):
         d2 = wk.sq_dists(gp, z, block_s=bs, block_d=bd, interpret=interpret)
-        w = 1.0 / jnp.maximum(jnp.sqrt(d2), eps)
+        w = jnp.where(live, 1.0 / jnp.maximum(jnp.sqrt(d2), eps), 0.0)
         num = wk.weighted_sum(gp, w, block_s=bs, block_d=bd, interpret=interpret)
         return num / jnp.sum(w), None
 

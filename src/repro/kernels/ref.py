@@ -1,7 +1,11 @@
 """Pure-jnp oracles for every Pallas kernel (allclose targets).
 
 These are also the *algorithmic* reference: the kernels must match these
-bit-for-bit up to float reassociation.
+bit-for-bit up to float reassociation.  The aggregation references use
+no matmul: each contraction is an elementwise product and a sum, exact
+float32 on every backend.  On a TPU v5e an XLA float32 matmul over a long contraction
+lost ~2.6e-5 relative even at ``Precision.HIGHEST``, where the Mosaic
+kernels held ~3e-7, so a matmul reference would be the less exact side.
 """
 from __future__ import annotations
 
@@ -15,7 +19,7 @@ def dot_norms_ref(g: jnp.ndarray, r: jnp.ndarray):
     """g: [S, d], r: [d] -> (dots [S], g_sq [S], r_sq [])  (f32 accum)."""
     gf = g.astype(jnp.float32)
     rf = r.astype(jnp.float32)
-    dots = gf @ rf
+    dots = jnp.sum(gf * rf[None, :], axis=1)
     g_sq = jnp.sum(gf * gf, axis=1)
     r_sq = jnp.sum(rf * rf)
     return dots, g_sq, r_sq
@@ -63,7 +67,7 @@ def blend_reduce_ref(g, r, aw, bw):
     """Delta = sum_s (aw_s g_s + bw_s r)  -> [d]  (f32)."""
     gf = g.astype(jnp.float32)
     rf = r.astype(jnp.float32)
-    return jnp.einsum("s,sd->d", aw.astype(jnp.float32), gf) + jnp.sum(
+    return jnp.sum(aw.astype(jnp.float32)[:, None] * gf, axis=0) + jnp.sum(
         bw.astype(jnp.float32)
     ) * rf
 
@@ -77,7 +81,7 @@ def weiszfeld_distances_ref(g, z):
 def weighted_mean_ref(g, w):
     """[S,d], [S] -> sum_s w_s g_s / sum_s w_s."""
     wf = w.astype(jnp.float32)
-    num = jnp.einsum("s,sd->d", wf, g.astype(jnp.float32))
+    num = jnp.sum(wf[:, None] * g.astype(jnp.float32), axis=0)
     return (num / jnp.sum(wf)).astype(g.dtype)
 
 
@@ -123,7 +127,8 @@ def pairwise_sq_dists_ref(g):
     """[S, d] -> [S, S] squared distances (Gram identity, f32)."""
     f32 = g.astype(jnp.float32)
     sq = jnp.sum(f32 * f32, axis=-1)
-    return jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * (f32 @ f32.T), 0.0)
+    gram = jax.lax.map(lambda row: jnp.sum(f32 * row, axis=-1), f32)
+    return jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * gram, 0.0)
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=None, scale=None):

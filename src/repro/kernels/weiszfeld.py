@@ -5,14 +5,19 @@ Per iteration over ``G:[S, d]`` and current estimate ``z:[d]``:
 
 Kernel 1 (``sq_dists``): per-worker squared distances, one HBM pass over
 G with VMEM accumulation across d-tiles.
-Kernel 2 (``weighted_mean``): one HBM pass producing the reweighted mean
+Kernel 2 (``weighted_sum``): one HBM pass producing the reweighted sum
 with the [S] weight vector resident in VMEM.
+
+Per-worker vectors use the ``[S, 1]`` column layout and the weighted sum
+of ``kernels.drag_calibrate``, under the same Mosaic layout rules.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.drag_calibrate import column, row_block, row_weighted_sum
 
 DEF_BS = 8
 DEF_BD = 1024
@@ -28,27 +33,28 @@ def _sq_dists_kernel(g_ref, z_ref, out_ref):
     g = g_ref[...].astype(jnp.float32)
     z = z_ref[...].astype(jnp.float32)
     diff = g - z[None, :]
-    out_ref[...] += jnp.sum(diff * diff, axis=1)
+    out_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True)
 
 
 def sq_dists(g, z, *, block_s=DEF_BS, block_d=DEF_BD, interpret=False):
     s, d = g.shape
     bs, bd = min(block_s, s), min(block_d, d)
     assert s % bs == 0 and d % bd == 0
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _sq_dists_kernel,
         grid=(s // bs, d // bd),
         in_specs=[
             pl.BlockSpec((bs, bd), lambda i, j: (i, j)),
             pl.BlockSpec((bd,), lambda i, j: (j,)),
         ],
-        out_specs=pl.BlockSpec((bs,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((s,), jnp.float32),
+        out_specs=row_block(bs, 0),
+        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.float32),
         interpret=interpret,
     )(g, z)
+    return out[:, 0]
 
 
-def _weighted_mean_kernel(g_ref, w_ref, out_ref, *, s_total: int):
+def _weighted_sum_kernel(g_ref, w_ref, out_ref):
     i = pl.program_id(1)  # worker-tile index (reduction axis)
 
     @pl.when(i == 0)
@@ -56,8 +62,7 @@ def _weighted_mean_kernel(g_ref, w_ref, out_ref, *, s_total: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     g = g_ref[...].astype(jnp.float32)  # [bs, bd]
-    w = w_ref[...].astype(jnp.float32)  # [bs]
-    out_ref[...] += w @ g
+    out_ref[...] += row_weighted_sum(w_ref[...], g)  # w: [bs, 1]
 
 
 def weighted_sum(g, w, *, block_s=DEF_BS, block_d=DEF_BD, interpret=False):
@@ -65,16 +70,14 @@ def weighted_sum(g, w, *, block_s=DEF_BS, block_d=DEF_BD, interpret=False):
     s, d = g.shape
     bs, bd = min(block_s, s), min(block_d, d)
     assert s % bs == 0 and d % bd == 0
-    import functools
-
     return pl.pallas_call(
-        functools.partial(_weighted_mean_kernel, s_total=s),
+        _weighted_sum_kernel,
         grid=(d // bd, s // bs),  # d outer so the out tile stays resident
         in_specs=[
             pl.BlockSpec((bs, bd), lambda j, i: (i, j)),
-            pl.BlockSpec((bs,), lambda j, i: (i,)),
+            row_block(bs, 1),
         ],
         out_specs=pl.BlockSpec((bd,), lambda j, i: (j,)),
         out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
         interpret=interpret,
-    )(g, w)
+    )(g, column(w))

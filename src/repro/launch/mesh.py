@@ -6,12 +6,21 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the sharded ingest's scatter and
+    gather rely on GSPMD propagation, which Explicit axes (the default
+    since jax 0.7) reject with a ``ShardingTypeError``."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(devices=None):
@@ -19,7 +28,7 @@ def make_test_mesh(devices=None):
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
     d = 2 if n % 2 == 0 and n > 1 else 1
-    return jax.make_mesh((d, n // d), ("data", "model"), devices=devices[: d * (n // d)])
+    return _auto_mesh((d, n // d), ("data", "model"), devices[: d * (n // d)])
 
 
 def make_pod_mesh(n_pods: int, devices=None):
@@ -31,7 +40,7 @@ def make_pod_mesh(n_pods: int, devices=None):
         raise ValueError(
             f"need {n_pods} devices for {n_pods} pods, have {len(devices)}"
         )
-    return jax.make_mesh((n_pods,), ("pod",), devices=devices[:n_pods])
+    return _auto_mesh((n_pods,), ("pod",), devices[:n_pods])
 
 
 def batch_axes_of(mesh) -> tuple:

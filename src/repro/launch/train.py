@@ -26,7 +26,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, param_count
 from repro.core import pytree as pt
-from repro.launch import compat
 from repro.launch.mesh import batch_axes_of
 from repro.models import transformer as T
 from repro.optim import get_optimizer
@@ -84,16 +83,7 @@ def make_fl_round_step(
     # sufficient: GSPMD back-propagates the TP layout onto the weights.
     # (Directly constraining the param tree in-body trips an XLA SPMD
     # partitioner CHECK at 256 devices — see EXPERIMENTS.md §Perf H3.)
-    # Legacy shard_map (jax.experimental, pre-jax.shard_map installs)
-    # CHECK-crashes the XLA partitioner when the scanned layer stack's
-    # backward pass meets a partial-auto manual subgroup; fall back to a
-    # FULLY manual body there — every mesh axis manual, params replicated
-    # over the model axis (redundant TP compute, identical numerics).
-    act = rules.act_specs(arch, None) if compat.HAS_NATIVE_SHARD_MAP else {}
-    shard = rules.make_shard_fn(mesh, act, use_pspec=True)
-    manual_axes = (
-        {client_axis} if compat.HAS_NATIVE_SHARD_MAP else set(mesh.axis_names)
-    )
+    shard = rules.make_shard_fn(mesh, rules.act_specs(arch, None), use_pspec=True)
 
     def local_loss(p, mb):
         return T.loss_fn(p, arch, mb, shard=shard, remat=True)
@@ -171,12 +161,15 @@ def make_fl_round_step(
                     jax.tree.map(lambda _: P(), maybe_root[0]),
                 )
             out_specs = (p_sm_spec, p_sm_spec, {k: P() for k in ("dod_mean", "update_norm_mean", "delta_norm")})
-            body = compat.shard_map(
+            # replication checking off: the body mixes manual collectives
+            # with GSPMD-auto axes, which the checker rejects
+            body = jax.shard_map(
                 round_body,
                 mesh=mesh,
-                axis_names=manual_axes,
+                axis_names={client_axis},
                 in_specs=in_specs,
                 out_specs=out_specs,
+                check_vma=False,
             )
             return body(params, reference, batch, *maybe_root)
 

@@ -773,7 +773,9 @@ def run_stream_experiment(
     check: bool = True,  # False: spec already validated (api.compile)
 ) -> dict:
     """Event-driven training run; returns a history dict with accuracy,
-    staleness, and throughput (virtual + wall) per eval point."""
+    staleness, and throughput (virtual + wall) per eval point, the final
+    ``params`` pytree and, for sharded buffers, ``slot_devices``: how
+    many devices hold the pod slots."""
     from repro.api import lowering
     from repro.api.validation import ensure_executable, validate
     from repro.data.pipeline import build_federated_data
@@ -985,6 +987,9 @@ def run_stream_experiment(
     history["final_accuracy"] = history["accuracy"][-1] if history["accuracy"] else 0.0
     history["updates_total"] = updates_total
     history["updates_per_wall_s"] = updates_total / max(time.time() - t0, 1e-9)
+    history["params"] = server.params
+    if cfg.shards:
+        history["slot_devices"] = len(server.state.buffer.slots.sharding.device_set)
     if server.root_cache is not None:
         history["root_cache_hits"] = server.root_cache.hits
         history["root_cache_misses"] = server.root_cache.misses

@@ -46,13 +46,13 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import drag
 from repro.core import flat as flat_mod
 from repro.core import pytree as pt
 from repro.kernels import ops as kops
-from repro.launch import compat
 from repro.stream import buffer as buffer_mod
 
 #: the mesh axis the sub-buffers shard over (``launch.mesh.make_pod_mesh``)
@@ -255,11 +255,14 @@ def psum_bundle(bundle: pt.Pytree, axis_name: str | None):
     scattered per-row DoD/trust scalars — rides this single call: one
     ``psum`` primitive over the pod mesh axis, or (emulation,
     ``axis_name=None``) one tree-sum over the stacked leading pod axis.
+    A psum of a pytree is one collective per leaf, so the leaves are
+    packed into one flat buffer first and unpacked after.
     ``kernels.instrument.count_collective_calls`` counts invocations,
     which is how the one-psum invariant is asserted.
     """
     if axis_name is not None:
-        return jax.lax.psum(bundle, axis_name)
+        flat, unpack = ravel_pytree(bundle)
+        return unpack(jax.lax.psum(flat, axis_name))
     # emulation: leaves are [p, ...] stacked partials.  p == 1 is a pure
     # slice — no arithmetic — which keeps the p=1 path bit-for-bit.
     return jax.tree.map(
@@ -358,13 +361,14 @@ def hierarchical_flush(
             # r is replicated, so r_sq is already identical on every pod
             return red["delta"], red["dots"], red["gsq"], red["lam"], rsq_l
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             axis_names={pod_axis},
             in_specs=(P(pod_axis, None, None), P(), P(pod_axis, None),
                       P(pod_axis, None), P()),
             out_specs=(P(), P(), P(), P(), P()),
+            check_vma=False,
         )
         init_arg = jnp.asarray(False) if init is None else jnp.asarray(init)
         delta, dots, gsq, lam, rsq = fn(slots3, r_flat, w2, disc2, init_arg)

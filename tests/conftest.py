@@ -5,15 +5,9 @@ here — smoke tests must see the default single CPU device; the 512-device
 dry-run paths run in subprocesses (tests/test_launch.py).
 
 A persistent compilation cache keeps repeated full-suite runs fast (the
-unrolled FL round programs dominate compile time otherwise).
+unrolled FL round programs dominate compile time otherwise); see
+``repro.compile_cache`` for where it lives.
 """
-import os
+from repro.compile_cache import enable_compile_cache
 
-import jax
-
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(__file__), "..", ".jax_cache"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+enable_compile_cache()

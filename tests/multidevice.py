@@ -13,6 +13,9 @@ reports structured results (errors, counts) back to the asserting test.
 
 Used by ``tests/test_launch.py`` (sharded-lowering / dry-run paths) and
 ``tests/test_sharded_buffer.py`` (pod-sharded ingest buffer parity).
+
+CPU only: a parent that has touched JAX holds the accelerator, so it must
+never spawn children that need the chip.
 """
 from __future__ import annotations
 

@@ -427,9 +427,15 @@ class TestTelemetryInvariance:
         m_on = dict(m_on)
         obs = m_on.pop("obs")
         assert int(obs.fill) == 4
-        for a, b in zip(jax.tree.leaves((s_off.params, m_off)),
-                        jax.tree.leaves((s_on.params, m_on))):
+        for a, b in zip(jax.tree.leaves(s_off.params), jax.tree.leaves(s_on.params)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # the params stay bit-for-bit; a metric is a reduction that XLA
+        # may fuse differently once the bundle also reads its inputs, which
+        # moves its last bit or two (update_norm_mean: 1 ULP on jax 0.9)
+        assert m_off.keys() == m_on.keys()
+        for k in m_off:
+            np.testing.assert_array_max_ulp(
+                np.asarray(m_off[k]), np.asarray(m_on[k]), maxulp=2)
 
 
 class TestEndToEnd:
