@@ -36,6 +36,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import pytree as pt
 
@@ -94,6 +95,17 @@ def stacked_spec_of(stacked: pt.Pytree) -> StackSpec:
 def flatten_tree(tree: pt.Pytree) -> jax.Array:
     """One pytree -> flat f32 ``[d]`` vector (leaf traversal order)."""
     return pt.tree_flatten_vector(tree)
+
+
+def flatten_host(tree: pt.Pytree):
+    """A pytree of host (numpy) leaves -> the :func:`flatten_tree` row,
+    built on the host as one contiguous f32 ``[d]`` array, so an upload
+    off the wire crosses to the device as one transfer instead of one per
+    leaf.  Any other tree (device arrays) is returned unchanged."""
+    leaves = jax.tree.leaves(tree)
+    if not leaves or not all(isinstance(x, np.ndarray) for x in leaves):
+        return tree
+    return np.concatenate([np.asarray(x, np.float32).ravel() for x in leaves])
 
 
 def unflatten_tree(vec: jax.Array, spec: StackSpec) -> pt.Pytree:

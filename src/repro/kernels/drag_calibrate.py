@@ -126,6 +126,7 @@ def dot_norms(g, r, *, block_s: int = DEF_BS, block_d: int = DEF_BD, interpret: 
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="dot_norms",
     )(g, r)
     return dots[:, 0], gsq[:, 0], rsq[0, 0]
 
@@ -155,6 +156,7 @@ def blend(g, r, a, b, *, block_s: int = DEF_BS, block_d: int = DEF_BD, interpret
         out_specs=pl.BlockSpec((bs, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((s, d), g.dtype),
         interpret=interpret,
+        name="blend",
     )(g, r, column(a), column(b))
 
 
@@ -201,6 +203,7 @@ def blend_reduce(g, r, aw, bw, *, block_s: int = DEF_BS, block_d: int = DEF_BD,
         out_specs=pl.BlockSpec((bd,), lambda j, i: (j,)),
         out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
         interpret=interpret,
+        name="blend_reduce",
     )(g, r, column(aw), column(bw))
 
 
@@ -257,5 +260,6 @@ def fused_flush(g, r, phi, w, u, sel, *, c: float, mode: str,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_flush",
     )(g, r, column(phi), column(w), column(u), column(sel))
     return delta, dots[:, 0], gsq[:, 0], rsq[0, 0]

@@ -14,12 +14,13 @@ Two halves, with a hard boundary between them:
     can keep them device-resident.
 
   * **Host-side tracing + sinks** (``trace`` / ``sinks``): a
-    lightweight nestable span API (``obs.trace.span("ingest")``,
-    monotonic clock) over the engines' HOST boundaries — never inside
-    jit — with pluggable sinks: an in-memory recorder for tests
-    (:class:`~repro.obs.sinks.MemorySink`), a structured JSONL event
-    log (:class:`~repro.obs.sinks.JsonlSink`), and Chrome/Perfetto
-    ``trace_event`` export (:func:`~repro.obs.sinks.perfetto_trace`).
+    lightweight nestable span API (``obs.trace.span("ingest")``, on the
+    profiler's wall clock, and a ``repro.<name>`` profiler annotation
+    while a ``jax.profiler`` session runs) over the engines' HOST
+    boundaries — never inside jit — with pluggable sinks: an in-memory
+    recorder for tests (:class:`~repro.obs.sinks.MemorySink`), a
+    structured JSONL event log (:class:`~repro.obs.sinks.JsonlSink`),
+    and Chrome/Perfetto ``trace_event`` export (:func:`~repro.obs.sinks.perfetto_trace`).
 
 On top of the recording halves sits the **diagnosis layer** (PR 7):
 ``monitor`` runs jittable online change-point detectors (EWMA residual

@@ -71,9 +71,10 @@ class JsonlSink:
 def perfetto_trace(events, process_name: str = "repro") -> dict:
     """Chrome/Perfetto ``trace_event`` JSON from a recorded event list.
 
-    Spans become complete ("X") events, counters "C", instants "i" —
-    the nesting Perfetto renders is the real span nesting because the
-    tracer's ``ts``/``dur`` come from one monotonic clock per thread.
+    Spans become complete ("X") events, counters "C", alerts
+    process-scoped instants "i" — the nesting Perfetto renders is the
+    real span nesting because the tracer's ``ts``/``dur`` come from one
+    clock, the profiler's (``repro.obs.trace``).
     """
     trace_events = [
         {
@@ -104,16 +105,6 @@ def perfetto_trace(events, process_name: str = "repro") -> dict:
                 "pid": 1,
                 "args": {"value": ev["value"]},
             })
-        elif kind == "instant":
-            trace_events.append({
-                "name": ev["name"],
-                "ph": "i",
-                "s": "t",
-                "ts": ev["ts_us"],
-                "pid": 1,
-                "tid": tid,
-                "args": ev.get("attrs", {}),
-            })
         elif kind == "alert":
             # alerts render as process-scoped instants so onset markers
             # line up against the flush spans they diagnosed
@@ -128,7 +119,6 @@ def perfetto_trace(events, process_name: str = "repro") -> dict:
                 "tid": tid,
                 "args": args,
             })
-        # meta events carry no timeline geometry; skipped by design
     return {"traceEvents": trace_events, "displayTimeUnit": "ms"}
 
 

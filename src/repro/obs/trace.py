@@ -1,23 +1,42 @@
-"""Host-side span tracing: nestable, monotonic-clock, sink-fanout.
+"""Host-side span tracing: nestable spans on the profiler's clock, sink
+fan-out, and profiler annotations.
 
 The span API instruments the engines' HOST boundaries — the event loop
 around the jitted flush, the dispatch/ingest path, eval points — never
 code inside jit (a traced region runs once at trace time; timing it
 would time compilation, not serving).  That boundary rule lives in
-ROADMAP §Observability plane.
+ROADMAP §System map.
 
 Everything funnels through one :class:`Tracer`:
 
-  * ``span(name, **attrs)`` — a context manager timing a host region
-    with ``time.perf_counter_ns``.  Spans nest: each records its parent
-    via a per-thread stack, so sinks can rebuild the tree and the
-    Perfetto export shows real nesting.
-  * ``counter(name, value)`` / ``instant(name)`` — point events (kernel
-    call counts, drop totals, flush markers).
+  * ``span(name, **attrs)`` — a context manager timing a host region.
+    Spans nest: each records its parent via a per-thread stack, so sinks
+    can rebuild the tree and the Perfetto export shows real nesting.
+    Child spans of one boundary share their parent's name as a prefix
+    (``ingest`` > ``ingest.h2d``, ``ingest.write``).
+  * ``counter(name, value)`` — a point value (kernel call counts, drop
+    totals).
+  * ``alert(signal, round)`` — a monitor detector fired.
 
-A DISABLED tracer (the default — telemetry is opt-in via
-``api.TelemetrySpec``) costs one attribute check per span: ``span``
-returns a shared no-op context manager and no event objects are built.
+A span is live when a sink is attached OR a ``jax.profiler`` session is
+running.  Under an active profiler a live span is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (``repro.flush``,
+``repro.megastep.dispatch``): it lands on the profiler's host timeline
+beside the device ops, and the ``repro.`` prefix keeps the program's
+spans apart from the runtime's own events and from a benchmark's spans.
+The annotation carries the name only, not the attrs.  With only the
+profiler on, no event dict is built.
+
+Clock: events are stamped with ``time.time_ns`` (the wall clock,
+``CLOCK_REALTIME``), the clock the profiler stamps its host events
+with, so an exported ``flush`` span and its ``repro.flush`` profiler
+event start at the same instant (``jax.profiler.ProfileData`` gives
+event times relative to the profile's ``profile_start_time``, itself
+on this clock).
+
+With neither a sink nor a profiler (the default — telemetry is opt-in
+via ``api.TelemetrySpec``) ``span`` costs one attribute check and one
+profiler-state check, and returns a shared no-op context manager.
 Events are plain dicts (the JSONL schema, ``benchmarks.validate``
 checks it) fanned out to the attached sinks.
 """
@@ -29,6 +48,9 @@ import threading
 import time
 from typing import Any, Callable
 
+from jax._src.lib import _profiler
+from jax.profiler import TraceAnnotation
+
 #: event schema version stamped on every emitted event (JSONL consumers
 #: and ``benchmarks/validate.py`` key on it)
 SCHEMA_VERSION = 1
@@ -38,17 +60,21 @@ SCHEMA_VERSION = 1
 EVENT_SCHEMA: dict[str, tuple[str, ...]] = {
     "span": ("type", "name", "ts_us", "dur_us", "tid"),
     "counter": ("type", "name", "ts_us", "value"),
-    "instant": ("type", "name", "ts_us"),
-    "meta": ("type", "name", "ts_us", "attrs"),
     # ``alert``: a monitor detector fired (repro.obs.monitor).  ``signal``
     # names the MONITOR_SIGNALS entry, ``round`` the server round of the
     # flush that tripped it; value/score evidence rides in ``attrs``.
     "alert": ("type", "name", "ts_us", "signal", "round"),
 }
 
+#: prefix of the spans' names on the profiler's timeline
+PROFILER_PREFIX = "repro."
+
+#: True while a ``jax.profiler`` session records host events
+_profiling: Callable[[], bool] = _profiler.TraceMe.is_enabled
+
 
 def _now_us() -> float:
-    return time.perf_counter_ns() / 1e3
+    return time.time_ns() / 1e3
 
 
 class _NullSpan:
@@ -69,10 +95,19 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _LiveSpan:
-    """One open span: collects attrs, emits on exit."""
+class _AnnotationSpan(TraceAnnotation):
+    """A span under an active profiler with no sink attached: the
+    profiler annotation alone, no event."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0", "parent", "span_id")
+    def set(self, **attrs) -> None:  # parity with _LiveSpan
+        pass
+
+
+class _LiveSpan:
+    """One open span: collects attrs, emits on exit, and is a profiler
+    annotation while a profiler session runs."""
+
+    __slots__ = ("tracer", "name", "attrs", "t0", "parent", "span_id", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
@@ -81,6 +116,7 @@ class _LiveSpan:
         self.t0 = 0.0
         self.parent = 0
         self.span_id = 0
+        self.ann = None
 
     def set(self, **attrs) -> None:
         """Attach attributes discovered mid-span (e.g. a flush's round)."""
@@ -91,11 +127,16 @@ class _LiveSpan:
         self.parent = stack[-1] if stack else 0
         self.span_id = next(self.tracer._ids)
         stack.append(self.span_id)
+        if _profiling():
+            self.ann = TraceAnnotation(PROFILER_PREFIX + self.name)
+            self.ann.__enter__()
         self.t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = _now_us()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         stack = self.tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -119,8 +160,10 @@ class Tracer:
     """Span/counter event source fanning out to attached sinks.
 
     Disabled (no sinks) by default; :meth:`attach`/:meth:`detach` flip
-    the ``enabled`` fast-path flag.  Sinks are host-side only: anything
-    with an ``emit(event: dict)`` method (``repro.obs.sinks``).
+    the ``enabled`` flag, which says whether events are recorded; a
+    running profiler makes spans live as annotations either way.  Sinks
+    are host-side only: anything with an ``emit(event: dict)`` method
+    (``repro.obs.sinks``).
     """
 
     def __init__(self) -> None:
@@ -152,10 +195,14 @@ class Tracer:
 
     # ------------------------------------------------------------- the API
     def span(self, name: str, **attrs):
-        """Time a host-side region; nestable, no-op when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _LiveSpan(self, name, attrs)
+        """Time a host-side region; nestable.  An event for the sinks,
+        a ``repro.<name>`` annotation for a running profiler, else a
+        no-op."""
+        if self.enabled:
+            return _LiveSpan(self, name, attrs)
+        if _profiling():
+            return _AnnotationSpan(PROFILER_PREFIX + name)
+        return _NULL_SPAN
 
     def counter(self, name: str, value, **attrs) -> None:
         """Record a point value (a count, a rate) at the current time."""
@@ -166,21 +213,6 @@ class Tracer:
             "name": name,
             "ts_us": _now_us(),
             "value": float(value),
-            "tid": threading.get_ident() & 0xFFFF,
-            "v": SCHEMA_VERSION,
-        }
-        if attrs:
-            ev["attrs"] = attrs
-        self._emit(ev)
-
-    def instant(self, name: str, **attrs) -> None:
-        """Mark a point in time (a flush, a quarantine decision)."""
-        if not self.enabled:
-            return
-        ev = {
-            "type": "instant",
-            "name": name,
-            "ts_us": _now_us(),
             "tid": threading.get_ident() & 0xFFFF,
             "v": SCHEMA_VERSION,
         }
@@ -204,18 +236,6 @@ class Tracer:
         if attrs:
             ev["attrs"] = attrs
         self._emit(ev)
-
-    def meta(self, name: str, attrs: dict) -> None:
-        """Session metadata (spec provenance, engine identity)."""
-        if not self.enabled:
-            return
-        self._emit({
-            "type": "meta",
-            "name": name,
-            "ts_us": _now_us(),
-            "attrs": attrs,
-            "v": SCHEMA_VERSION,
-        })
 
     @contextlib.contextmanager
     def attached(self, *sinks):
@@ -245,10 +265,6 @@ def span(name: str, **attrs):
 
 def counter(name: str, value, **attrs) -> None:
     tracer.counter(name, value, **attrs)
-
-
-def instant(name: str, **attrs) -> None:
-    tracer.instant(name, **attrs)
 
 
 def aggregate_spans(events) -> dict[str, dict[str, float]]:

@@ -38,7 +38,8 @@ Megastep boundary rules (see ROADMAP "Compiled serving loop"):
     survives compilation).
   * AT the host boundary (once per chunk, never per event): eval, the
     telemetry-ring drain into the session, monitor verdict decode,
-    the ``megastep`` trace span, and the next chunk's root batches.
+    the ``megastep`` trace span and its children
+    (``CompiledStream._run_chunk``), and the next chunk's root batches.
 """
 from __future__ import annotations
 
@@ -399,6 +400,27 @@ class CompiledStream:
         return {name: np.concatenate(v) for name, v in out.items()}
 
     def _run_chunk(self, c: int) -> dict:
+        """One host call of ``c`` flushes.  Spans: ``megastep`` over the
+        whole chunk, with children ``megastep.prepare`` (the arrivals
+        slice, root batches and argument arrays), ``megastep.dispatch``
+        (the compiled call, until it returns), ``megastep.wait`` (until
+        the device has finished) and ``megastep.drain`` (host mirrors,
+        ring drain and the per-flush metrics brought to the host)."""
+        with obs_trace.span(MEGASTEP_SPAN, flushes=c, block=self.block):
+            with obs_trace.span("megastep.prepare"):
+                args, refresh = self._chunk_args(c)
+            with obs_trace.span("megastep.dispatch"):
+                carry, ys = self._fns[c](*args)
+            # sync in a span of its own: dispatch is asynchronous, and the
+            # host mirrors below would otherwise absorb the device time
+            with obs_trace.span("megastep.wait"):
+                jax.block_until_ready((carry.params, ys))
+            with obs_trace.span("megastep.drain"):
+                return self._drain(c, carry, ys, refresh)
+
+    def _chunk_args(self, c: int):
+        """The compiled call's arguments for a chunk of ``c`` flushes,
+        and its root-refresh schedule (None without a root reference)."""
         server, cfg = self.server, self.server.cfg
         if c not in self._fns:
             self._fns[c] = make_megastep(
@@ -429,15 +451,15 @@ class CompiledStream:
                 "y": jnp.asarray(np.stack([r["y"] for r in roots])),
             }
             args += [jnp.asarray(refresh), root]
-        with obs_trace.span(MEGASTEP_SPAN, flushes=c, block=self.block):
-            carry, ys = self._fns[c](*args)
-            # sync inside the span: dispatch is asynchronous, and the
-            # host mirrors below would otherwise absorb the device time
-            jax.block_until_ready((carry.params, ys))
+        return args, refresh
+
+    def _drain(self, c: int, carry, ys, refresh) -> dict:
+        """Host side of a finished chunk: the bookkeeping the legacy loop
+        keeps, the telemetry drained into the session, and the per-flush
+        metrics as numpy arrays."""
+        server, cfg = self.server, self.server.cfg
         self._carry = carry
         self._events_done += c * self.k
-
-        # ---- host mirrors: the same bookkeeping the legacy loop keeps
         server.state = server_mod.StreamState(
             params=carry.params, round=carry.rnd, drag=carry.drag,
             buffer=carry.buffer, adversary=carry.adversary, trust=carry.trust,

@@ -620,7 +620,14 @@ class AsyncStreamServer:
     ) -> bool:
         """Accept one upload.  Returns False — and counts the drop — when
         the buffer is already at threshold; call ``flush_if_ready`` first
-        if the update must not be lost."""
+        if the update must not be lost.
+
+        Spans: ``ingest``, and on the unsharded path its children
+        ``ingest.h2d`` (the upload's host-to-device copy: a numpy upload
+        is flattened on the host and crosses as one row) and
+        ``ingest.write`` (the jitted, donated buffer write).  The sharded
+        path routes the upload to a pod inside its jitted write, so it
+        keeps the single ``ingest`` span."""
         with obs_trace.span("ingest", client_id=int(client_id)) as sp:
             if self.ingested >= self.cfg.buffer_capacity:
                 self.dropped += 1
@@ -629,11 +636,19 @@ class AsyncStreamServer:
                 self.session.record_drop(client_id)
                 sp.set(dropped=True)
                 return False
-            self.state = self.state._replace(
-                buffer=self._ingest(
+            if self.cfg.shards > 0:
+                buffer = self._ingest(
                     self.state.buffer, g, dispatch_round, is_malicious, client_id
                 )
-            )
+            else:
+                with obs_trace.span("ingest.h2d"):
+                    g = jax.device_put(flat_mod.flatten_host(g))
+                with obs_trace.span("ingest.write"):
+                    buffer = self._ingest(
+                        self.state.buffer, g, dispatch_round, is_malicious,
+                        client_id,
+                    )
+            self.state = self.state._replace(buffer=buffer)
             self.ingested += 1
             return True
 
@@ -647,6 +662,13 @@ class AsyncStreamServer:
         return self.root_cache.get(self.t, self.state.params, root_batches)
 
     def flush_if_ready(self, key, root_batches=None) -> dict | None:
+        """Flush the buffer once it holds K uploads; None before that.
+
+        Spans: ``flush``, with children ``root_reference`` (BR-DRAG and
+        FLTrust), ``flush.dispatch`` (the jitted flush call, from entry
+        to return; ``sharded_flush`` on the sharded path) and
+        ``flush.mirror`` (the host-side state, alert decode and the
+        telemetry ring push)."""
         if not self.buffer_ready():
             return None
         with obs_trace.span("flush", round=self.t, shards=self.cfg.shards):
@@ -659,33 +681,30 @@ class AsyncStreamServer:
                 with obs_trace.span("root_reference"):
                     args.append(self.root_reference(root_batches))
             args.append(self.state.monitor)
-            if self.cfg.shards > 0:
-                # sharded span parity: the hierarchical one-psum flush
-                # gets its own nested span (host boundary — never in jit)
-                with obs_trace.span(
-                    sharded_mod.FLUSH_SPAN, **sharded_mod.span_attrs(self.cfg)
-                ):
-                    params, new_drag, rnd, buf, adv, trust, metrics = (
-                        self._flush(*args)
-                    )
-            else:
+            # the sharded one-psum flush keeps its own span name (host
+            # boundary — never in jit)
+            dispatch = obs_trace.span(
+                sharded_mod.FLUSH_SPAN, **sharded_mod.span_attrs(self.cfg)
+            ) if self.cfg.shards > 0 else obs_trace.span("flush.dispatch")
+            with dispatch:
                 params, new_drag, rnd, buf, adv, trust, metrics = (
                     self._flush(*args)
                 )
-            new_monitor = self.state.monitor
-            obs_mon = metrics.pop("obs_monitor", None)
-            if obs_mon is not None:
-                new_monitor, verdict = obs_mon
-                self.session.record_alerts(verdict, new_monitor)
-            self.state = StreamState(
-                params=params, round=rnd, drag=new_drag, buffer=buf,
-                adversary=adv, trust=trust, monitor=new_monitor,
-            )
-            self.t += 1
-            self.ingested = 0
-            # the bundle is telemetry, not a training metric: it leaves the
-            # metrics dict here and accumulates in the session's ring
-            self.session.record_flush(metrics.pop("obs", None))
+            with obs_trace.span("flush.mirror"):
+                new_monitor = self.state.monitor
+                obs_mon = metrics.pop("obs_monitor", None)
+                if obs_mon is not None:
+                    new_monitor, verdict = obs_mon
+                    self.session.record_alerts(verdict, new_monitor)
+                self.state = StreamState(
+                    params=params, round=rnd, drag=new_drag, buffer=buf,
+                    adversary=adv, trust=trust, monitor=new_monitor,
+                )
+                self.t += 1
+                self.ingested = 0
+                # the bundle is telemetry, not a training metric: it leaves
+                # the metrics dict here and accumulates in the session's ring
+                self.session.record_flush(metrics.pop("obs", None))
             return metrics
 
     def serve_compiled(

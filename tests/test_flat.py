@@ -90,6 +90,18 @@ class TestUpdateStack:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+    @pytest.mark.parametrize("dtype", [np.float32, np.float64])
+    def test_flatten_host_is_flatten_tree(self, dtype):
+        """A numpy upload flattened on the host is the device row, bit for
+        bit; a tree of device arrays passes through untouched."""
+        tree = jax.tree.map(lambda x: np.asarray(x[0], dtype), _ups(jax.random.PRNGKey(4)))
+        row = flat_mod.flatten_host(tree)
+        assert isinstance(row, np.ndarray) and row.dtype == np.float32
+        np.testing.assert_array_equal(row, np.asarray(flat_mod.flatten_tree(tree)))
+        on_device = jax.tree.map(jnp.asarray, tree)
+        assert flat_mod.flatten_host(on_device) is on_device
+
+
 class TestFlatOracleParity:
     """ISSUE acceptance: flat path numerically matches the pytree oracle
     for drag, br_drag, fltrust, and trimmed_mean (atol/rtol 1e-5)."""

@@ -152,12 +152,77 @@ class TestMetricsRing:
 
 
 # ------------------------------------------------------- spans and sinks
+def _profiled_host_events(trace_dir):
+    """({name: [(start_ns, end_ns)]} of the host events, the profile's
+    start on the wall clock in ns) from a ``jax.profiler.trace`` dir."""
+    import glob
+
+    path, = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    events, start_ns = {}, None
+    for plane in pd.planes:
+        for key, value in plane.stats:
+            if key == "profile_start_time":
+                start_ns = value
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append((e.start_ns, e.end_ns))
+    return events, start_ns
+
+
 class TestTrace:
     def test_disabled_tracer_emits_nothing(self):
         sink = MemorySink()
         with obs_trace.span("nope"):
             pass
         assert sink.events == [] and not obs_trace.tracer.enabled
+
+    def test_disabled_span_is_the_shared_null_span(self, monkeypatch):
+        """No sink, no profiler: the shared no-op span, and no event is
+        built (the clock is never read)."""
+        def no_clock():
+            raise AssertionError("a disabled span read the clock")
+
+        monkeypatch.setattr(obs_trace, "_now_us", no_clock)
+        sp = obs_trace.span("ingest", client_id=3)
+        assert sp is obs_trace._NULL_SPAN
+        with sp as inner:
+            inner.set(dropped=True)
+
+    def test_profiler_alone_annotates_without_events(self, tmp_path, monkeypatch):
+        """A running profiler makes a span a ``repro.<name>`` annotation;
+        with no sink attached no event is built."""
+        def no_clock():
+            raise AssertionError("an annotation-only span read the clock")
+
+        monkeypatch.setattr(obs_trace, "_now_us", no_clock)
+        with jax.profiler.trace(str(tmp_path)):
+            with obs_trace.span("outer") as sp:
+                sp.set(round=1)
+                with obs_trace.span("outer.inner"):
+                    pass
+            assert obs_trace.span("x") is not obs_trace._NULL_SPAN
+        assert obs_trace.span("x") is obs_trace._NULL_SPAN  # profiler stopped
+        events, _ = _profiled_host_events(tmp_path)
+        (o0, o1), = events["repro.outer"]
+        (i0, i1), = events["repro.outer.inner"]
+        assert o0 <= i0 <= i1 <= o1
+
+    def test_span_events_are_on_the_profilers_clock(self, tmp_path):
+        """With a sink attached under a running profiler, the recorded
+        span and its ``repro.`` annotation start at the same instant."""
+        sink = MemorySink()
+        with jax.profiler.trace(str(tmp_path)):
+            with obs_trace.tracer.attached(sink):
+                with obs_trace.span("flush", round=0):
+                    jnp.ones(8).block_until_ready()
+        events, start_ns = _profiled_host_events(tmp_path)
+        (a, b), = events["repro.flush"]
+        ev, = sink.spans()
+        # the annotation opens just before the span's stamp is taken
+        assert abs((ev["ts_us"] * 1e3 - start_ns) - a) < 5e6
+        assert abs(ev["dur_us"] * 1e3 - (b - a)) < 5e6
 
     def test_span_nesting_and_aggregation(self):
         sink = MemorySink()
@@ -168,7 +233,6 @@ class TestTrace:
                 with obs_trace.span("inner"):
                     pass
             obs_trace.counter("drops", 3)
-            obs_trace.instant("flush")
         assert not obs_trace.tracer.enabled  # detached cleanly
         spans = sink.spans()
         # children emit before the parent closes
@@ -187,8 +251,8 @@ class TestTrace:
             with obs_trace.span("s"):
                 pass
             obs_trace.counter("c", 1.0)
-            obs_trace.instant("i")
-            obs_trace.tracer.meta("m", {"k": "v"})
+            obs_trace.tracer.alert("div_mean", round=1, value=0.5)
+        assert sorted(ev["type"] for ev in sink.events) == sorted(obs_trace.EVENT_SCHEMA)
         for ev in sink.events:
             for field in obs_trace.EVENT_SCHEMA[ev["type"]]:
                 assert field in ev, (ev["type"], field)
@@ -203,7 +267,7 @@ class TestTrace:
         lines = [json.loads(l) for l in open(path)]
         assert len(lines) == 1 and lines[0]["name"] == "a"
         with pytest.raises(ValueError, match="closed"):
-            sink.emit({"type": "instant", "name": "x", "ts_us": 0.0})
+            sink.emit({"type": "counter", "name": "x", "ts_us": 0.0, "value": 1.0})
 
     def test_perfetto_export_shape(self):
         sink = MemorySink()

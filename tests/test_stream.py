@@ -372,6 +372,21 @@ class TestAsyncServer:
         assert int(server.state.buffer.count) == 0
         assert float(metrics["staleness_mean"]) == 0.0
 
+    def test_numpy_upload_lands_as_its_device_twin(self):
+        """An upload off the wire (numpy leaves, flattened on the host)
+        fills the same slot, bit for bit, as the same upload on device."""
+        p = _params()
+        cfg = StreamConfig(algorithm="fedavg", buffer_capacity=2)
+        up = {"w": np.linspace(-1, 1, 6, dtype=np.float32).reshape(2, 3),
+              "b": np.array([0.5, -2.0], np.float32)}
+        slots = []
+        for g in (up, jax.tree.map(jnp.asarray, up)):
+            server = AsyncStreamServer(lambda p_, b: 0.0, p, cfg)
+            assert server.ingest(g, 0, False, client_id=3)
+            slots.append(np.asarray(server.state.buffer.slots[0]))
+        np.testing.assert_array_equal(slots[0], slots[1])
+        np.testing.assert_array_equal(slots[0], _flat(up))
+
     def test_run_stream_experiment_drag_poly(self):
         exp = StreamExperimentConfig(
             n_workers=10, concurrency=8, flushes=6, buffer_capacity=4,
