@@ -97,15 +97,46 @@ def flatten_tree(tree: pt.Pytree) -> jax.Array:
     return pt.tree_flatten_vector(tree)
 
 
+def _host_leaves(tree: pt.Pytree):
+    """The leaves of an all-numpy pytree, or None for any other tree."""
+    leaves = jax.tree.leaves(tree)
+    if not leaves or not all(isinstance(x, np.ndarray) for x in leaves):
+        return None
+    return leaves
+
+
 def flatten_host(tree: pt.Pytree):
     """A pytree of host (numpy) leaves -> the :func:`flatten_tree` row,
     built on the host as one contiguous f32 ``[d]`` array, so an upload
     off the wire crosses to the device as one transfer instead of one per
     leaf.  Any other tree (device arrays) is returned unchanged."""
-    leaves = jax.tree.leaves(tree)
-    if not leaves or not all(isinstance(x, np.ndarray) for x in leaves):
+    leaves = _host_leaves(tree)
+    if leaves is None:
         return tree
     return np.concatenate([np.asarray(x, np.float32).ravel() for x in leaves])
+
+
+UPLOAD_META = 3  # words after the row: dispatch_round, malicious, client_id
+
+
+def pack_upload(tree: pt.Pytree, dispatch_round: int, malicious: bool, client_id: int):
+    """A pytree of host (numpy) leaves plus its ingest metadata -> one
+    contiguous f32 ``[d + UPLOAD_META]`` row: the first ``d`` words are
+    :func:`flatten_host`'s row, the last three hold the int32 bit patterns
+    of ``(dispatch_round, malicious, client_id)`` (exact for any int32;
+    ``repro.stream.buffer.ingest_packed`` bitcasts them back).  So an upload
+    off the wire and its tags cross to the device as one transfer.  Any
+    other tree (device arrays) gives None."""
+    leaves = _host_leaves(tree)
+    if leaves is None:
+        return None
+    d = sum(x.size for x in leaves)
+    row = np.empty(d + UPLOAD_META, np.float32)
+    np.concatenate([np.asarray(x, np.float32).ravel() for x in leaves], out=row[:d])
+    row[d:].view(np.int32)[:] = np.array(
+        (dispatch_round, int(malicious), client_id), np.int32
+    )
+    return row
 
 
 def unflatten_tree(vec: jax.Array, spec: StackSpec) -> pt.Pytree:

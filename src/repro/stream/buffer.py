@@ -120,6 +120,20 @@ def ingest(
     )
 
 
+def ingest_packed(buf: BufferState, row) -> BufferState:
+    """:func:`ingest` of one packed upload (``core.flat.pack_upload``): a
+    ``[d + 3]`` f32 row whose last three words are the int32 bit patterns
+    of ``(dispatch_round, malicious, client_id)``.  They are bitcast back,
+    with no float arithmetic on them, so the write and its tags are
+    :func:`ingest`'s bit for bit and the buffer and the row are the only
+    arguments: no host scalar crosses per upload.
+    """
+    d = buf.slots.shape[1]
+    # slice first: bitcasting the whole row would copy all of it
+    meta = jax.lax.bitcast_convert_type(row[d:], jnp.int32)
+    return ingest(buf, row[:d], meta[0], meta[1] != 0, meta[2])
+
+
 def ingest_batch(buf: BufferState, rows, dispatch_rounds, malicious,
                  client_ids) -> BufferState:
     """Write B already-flat upload rows in one segment-scatter.
@@ -177,6 +191,11 @@ def as_stack(buf: BufferState, spec: flat_mod.StackSpec, server_round) -> flat_m
 def make_ingest_fn():
     """Jitted donated ingest: the buffer argument is consumed in place."""
     return jax.jit(ingest, donate_argnums=(0,))
+
+
+def make_ingest_packed_fn():
+    """Jitted donated packed ingest: (buffer, packed row) -> buffer."""
+    return jax.jit(ingest_packed, donate_argnums=(0,))
 
 
 def make_ingest_batch_fn():

@@ -599,6 +599,7 @@ class AsyncStreamServer:
             sharded_mod.make_ingest_fn() if cfg.shards > 0
             else buf_mod.make_ingest_fn()
         )
+        self._ingest_packed = buf_mod.make_ingest_packed_fn()
         self._flush = make_flush_fn(loss_fn, cfg, self.with_root, mesh)
         self._client = make_client_fn(loss_fn, cfg)
         self.root_cache = RootReferenceCache(
@@ -607,6 +608,7 @@ class AsyncStreamServer:
         self.t = 0  # host-side mirror of state.round (avoids device syncs)
         self.ingested = 0  # accepted since last flush (mirrors buffer.count)
         self.dropped = 0  # uploads refused because the buffer was full
+        self.packed_ingests = 0  # accepted numpy uploads written as one packed row
 
     @property
     def params(self) -> pt.Pytree:
@@ -622,12 +624,17 @@ class AsyncStreamServer:
         the buffer is already at threshold; call ``flush_if_ready`` first
         if the update must not be lost.
 
-        Spans: ``ingest``, and on the unsharded path its children
-        ``ingest.h2d`` (the upload's host-to-device copy: a numpy upload
-        is flattened on the host and crosses as one row) and
-        ``ingest.write`` (the jitted, donated buffer write).  The sharded
-        path routes the upload to a pod inside its jitted write, so it
-        keeps the single ``ingest`` span."""
+        On the unsharded path a numpy upload off the wire is packed on
+        the host with its tags (``core.flat.pack_upload``), so it costs
+        one transfer and one execute of a write that takes no host
+        scalars; it is counted in ``packed_ingests``.  A device upload
+        keeps the scalar-tagged write.
+
+        Spans: ``ingest`` (with ``packed`` on the unsharded path), and on
+        the unsharded path its children ``ingest.h2d`` (the upload's
+        host-to-device copy) and ``ingest.write`` (the jitted, donated
+        buffer write).  The sharded path routes the upload to a pod inside
+        its jitted write, so it keeps the single ``ingest`` span."""
         with obs_trace.span("ingest", client_id=int(client_id)) as sp:
             if self.ingested >= self.cfg.buffer_capacity:
                 self.dropped += 1
@@ -642,12 +649,20 @@ class AsyncStreamServer:
                 )
             else:
                 with obs_trace.span("ingest.h2d"):
-                    g = jax.device_put(flat_mod.flatten_host(g))
-                with obs_trace.span("ingest.write"):
-                    buffer = self._ingest(
-                        self.state.buffer, g, dispatch_round, is_malicious,
-                        client_id,
+                    row = flat_mod.pack_upload(
+                        g, dispatch_round, is_malicious, client_id
                     )
+                    g = jax.device_put(g if row is None else row)
+                sp.set(packed=row is not None)
+                with obs_trace.span("ingest.write"):
+                    if row is None:
+                        buffer = self._ingest(
+                            self.state.buffer, g, dispatch_round, is_malicious,
+                            client_id,
+                        )
+                    else:
+                        buffer = self._ingest_packed(self.state.buffer, g)
+                        self.packed_ingests += 1
             self.state = self.state._replace(buffer=buffer)
             self.ingested += 1
             return True

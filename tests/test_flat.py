@@ -101,6 +101,33 @@ class TestUpdateStack:
         on_device = jax.tree.map(jnp.asarray, tree)
         assert flat_mod.flatten_host(on_device) is on_device
 
+    @pytest.mark.parametrize("client_id", [0, 1, 1999, 2**31 - 1])
+    @pytest.mark.parametrize("dispatch_round", [0, 2**31 - 2])
+    @pytest.mark.parametrize("malicious", [False, True])
+    def test_pack_upload_round_trips_bit_for_bit(
+        self, client_id, dispatch_round, malicious
+    ):
+        """The packed row is flatten_host's row followed by the int32 bit
+        patterns of the tags; small ints are f32 denormals as bit patterns,
+        so they must survive as bits, not as floats."""
+        tree = jax.tree.map(lambda x: np.asarray(x[0]), _ups(jax.random.PRNGKey(5)))
+        row = flat_mod.pack_upload(tree, dispatch_round, malicious, client_id)
+        d = flat_mod.spec_of(tree).d
+        assert row.dtype == np.float32 and row.shape == (d + flat_mod.UPLOAD_META,)
+        np.testing.assert_array_equal(
+            row[:d].view(np.int32), flat_mod.flatten_host(tree).view(np.int32)
+        )
+        assert row[d:].view(np.int32).tolist() == [
+            dispatch_round, int(malicious), client_id
+        ]
+        # and back through the device bitcast the packed write uses
+        meta = jax.lax.bitcast_convert_type(jnp.asarray(row), jnp.int32)[d:]
+        assert np.asarray(meta).tolist() == [dispatch_round, int(malicious), client_id]
+
+    def test_pack_upload_refuses_device_trees(self):
+        tree = jax.tree.map(lambda x: x[0], _ups(jax.random.PRNGKey(5)))
+        assert flat_mod.pack_upload(tree, 0, False, 0) is None
+
 
 class TestFlatOracleParity:
     """ISSUE acceptance: flat path numerically matches the pytree oracle

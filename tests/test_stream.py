@@ -152,6 +152,42 @@ class TestBuffer:
         )
         np.testing.assert_array_equal(np.asarray(b1.slots), np.asarray(b2.slots))
 
+    def test_packed_write_matches_ingest_field_for_field(self):
+        """The packed write leaves every BufferState field as ingest does,
+        through a full buffer and a refused write (counted in drops)."""
+        from repro.core import flat as flat_mod
+
+        p = jax.tree.map(np.asarray, _params())
+        tags = [(0, False, 0), (7, True, 1), (2**31 - 2, False, 1999),
+                (3, True, 2**31 - 1)]
+        a = buf_mod.init_buffer(p, capacity=3)
+        b = buf_mod.init_buffer(p, capacity=3)
+        packed = buf_mod.make_ingest_packed_fn()
+        for i, (rnd, mal, cid) in enumerate(tags):  # the 4th is refused
+            g = jax.tree.map(lambda x: x * (i + 1.5), p)
+            a = buf_mod.ingest(a, g, rnd, mal, cid)
+            b = packed(b, jnp.asarray(flat_mod.pack_upload(g, rnd, mal, cid)))
+            for name, x, y in zip(a._fields, a, b):
+                assert x.dtype == y.dtype, name
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y), name)
+        assert int(b.count) == 3 and int(b.drops.sum()) == 1
+
+    def test_packed_write_takes_the_buffer_and_one_row(self):
+        """No host scalar is an argument of the packed write: its jitted
+        signature is the buffer's arrays and the packed row, nothing else."""
+        import inspect
+
+        from repro.core import flat as flat_mod
+
+        p = jax.tree.map(np.asarray, _params())
+        buf = buf_mod.init_buffer(p, 2)
+        row = flat_mod.pack_upload(p, 4, True, 9)
+        assert list(inspect.signature(buf_mod.ingest_packed).parameters) == ["buf", "row"]
+        jaxpr = jax.make_jaxpr(buf_mod.ingest_packed)(buf, row)
+        avals = [v.aval for v in jaxpr.jaxpr.invars]
+        assert len(avals) == len(buf) + 1
+        assert avals[-1].shape == row.shape and avals[-1].dtype == jnp.float32
+
     def test_as_stack_round_trips_metadata(self):
         from repro.core import flat as flat_mod
         from repro.stream import buffer as bm
@@ -386,6 +422,61 @@ class TestAsyncServer:
             slots.append(np.asarray(server.state.buffer.slots[0]))
         np.testing.assert_array_equal(slots[0], slots[1])
         np.testing.assert_array_equal(slots[0], _flat(up))
+
+    @staticmethod
+    def _drag_server(p):
+        cfg = StreamConfig(algorithm="drag", buffer_capacity=3, discount="poly",
+                           trust=True)
+        return AsyncStreamServer(lambda p_, b: 0.0, p, cfg, n_clients=40)
+
+    @staticmethod
+    def _uploads(n):
+        rng = np.random.RandomState(0)
+        return [{"w": rng.randn(2, 3).astype(np.float32),
+                 "b": rng.randn(2).astype(np.float32)} for _ in range(n)]
+
+    def test_numpy_and_device_uploads_flush_identically(self):
+        """Packed numpy uploads and the same uploads on the device (the
+        scalar-tagged write) give the same params, trust table and
+        metrics, flush for flush: client ids and staleness tags cross
+        exactly."""
+        p = _params()
+        ups = self._uploads(12)
+        runs = []
+        for on_device in (False, True):
+            server = self._drag_server(p)
+            flushes = []
+            for i, up in enumerate(ups):
+                g = jax.tree.map(jnp.asarray, up) if on_device else up
+                assert server.ingest(g, max(server.t - i % 2, 0), False,
+                                     client_id=5 + i % 4)
+                met = server.flush_if_ready(jax.random.PRNGKey(0))
+                if met is not None:
+                    flushes.append(jax.tree.map(
+                        np.asarray, (server.params, server.state.trust, met)))
+            runs.append(flushes)
+        assert len(runs[0]) == 4
+        for a, b in zip(*runs):
+            jax.tree.map(np.testing.assert_array_equal, a, b)
+
+    def test_packed_ingests_count_numpy_uploads_only(self):
+        """Every accepted numpy upload takes the packed write, no device
+        upload does, and the ``ingest`` span says which it took."""
+        from repro.obs import trace as obs_trace
+        from repro.obs.sinks import MemorySink
+
+        server = self._drag_server(_params())
+        ups = self._uploads(4)
+        sink = MemorySink()
+        with obs_trace.tracer.attached(sink):
+            for up in ups[:2]:
+                assert server.ingest(up, 0, False, client_id=1)
+            assert server.ingest(jax.tree.map(jnp.asarray, ups[2]), 0, False, 2)
+            assert not server.ingest(ups[3], 0, False, client_id=3)  # full
+        assert server.packed_ingests == 2 and server.dropped == 1
+        attrs = [s.get("attrs", {}) for s in sink.spans() if s["name"] == "ingest"]
+        assert [a.get("packed") for a in attrs] == [True, True, False, None]
+        assert attrs[-1]["dropped"] is True
 
     def test_run_stream_experiment_drag_poly(self):
         exp = StreamExperimentConfig(
