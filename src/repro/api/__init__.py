@@ -36,6 +36,7 @@ from repro.api.lowering import (  # noqa: F401
 )
 from repro.api.spec import (  # noqa: F401
     REGIMES,
+    AdapterSpec,
     AggregationSpec,
     AsyncRegime,
     AttackSpec,

@@ -37,6 +37,7 @@ from repro.api.spec import (
     TrustSpec,
 )
 from repro.fl.round import RoundConfig
+from repro.models.factory import is_arch
 from repro.stream.server import StreamConfig
 
 
@@ -106,6 +107,7 @@ def round_config(spec: ExperimentSpec) -> RoundConfig:
         trust_kw=kw_tuple(spec.trust.kwargs),
         telemetry=spec.telemetry.enabled and spec.telemetry.metrics,
         monitor=monitor_config(spec),
+        model_kind="arch" if is_arch(spec.model.name) else "cnn",
     )
 
 

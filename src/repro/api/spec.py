@@ -116,13 +116,42 @@ class DataSpec:
     root_samples: int = 3000  # |D_root| for BR-DRAG / FLTrust
     drift: str = "none"  # non-stationary data: none | label_shift
     drift_rate: float = 0.0  # label rotation speed (classes per round/flush)
+    seq_len: int = 0  # tokens per sequence of a token dataset
+
+
+@dataclasses.dataclass(frozen=True)
+class AdapterSpec:
+    """Low-rank adapters trained on a frozen base (``repro.models.lora``):
+    rank r, scale alpha / r, and the targets adapted (``attn``: every
+    attention projection; ``mlp``: dense MLPs; ``shared``: shared
+    experts)."""
+
+    rank: int = 16
+    alpha: float = 32.0
+    targets: tuple = ("attn", "mlp", "shared")
+
+    def __post_init__(self):
+        object.__setattr__(self, "targets", _freeze(self.targets))
 
 
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
-    """The trained architecture (``repro.models.cnn.MODELS`` name)."""
+    """The trained model: a ``repro.models.cnn.MODELS`` name, or an
+    architecture id of ``repro.configs.FL_ARCH_IDS`` whose clients train
+    ``adapters`` on its frozen base.  For an architecture, ``smoke``
+    takes its reduced CPU-test variant and ``overrides`` replaces
+    ArchConfig fields (dotted for a nested config, e.g.
+    ``moe.experts_held``): the chip's share of a deployment."""
 
     name: str = "mlp"
+    smoke: bool = False
+    overrides: dict = field(default_factory=dict)
+    adapters: AdapterSpec | None = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "overrides", _coerce_kwargs(self.overrides, "ModelSpec"))
+        if isinstance(self.adapters, Mapping):
+            object.__setattr__(self, "adapters", AdapterSpec(**self.adapters))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -293,7 +322,7 @@ class ShardedRegime(AsyncRegime):
     #   False: validate() demands a ("pod",) mesh (launch.mesh.make_pod_mesh)
 
 
-for _cls in (AttackSpec, TrustSpec, AsyncRegime, ShardedRegime):
+for _cls in (ModelSpec, AttackSpec, TrustSpec, AsyncRegime, ShardedRegime):
     _cls.__hash__ = _spec_hash  # dict kwargs fields; see _spec_hash
 
 
@@ -332,7 +361,10 @@ class ExperimentSpec:
         ``from_dict`` restores them)."""
         return {
             "data": dataclasses.asdict(self.data),
-            "model": dataclasses.asdict(self.model),
+            "model": {**dataclasses.asdict(self.model),
+                      "overrides": _thaw(self.model.overrides),
+                      "adapters": (None if self.model.adapters is None else
+                                   _thaw(dataclasses.asdict(self.model.adapters)))},
             "aggregation": dataclasses.asdict(self.aggregation),
             "attack": {"name": self.attack.name, "kwargs": _thaw(self.attack.kwargs)},
             "trust": {"enabled": self.trust.enabled, "kwargs": _thaw(self.trust.kwargs)},

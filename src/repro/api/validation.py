@@ -60,6 +60,40 @@ def async_algorithms() -> frozenset:
     return frozenset(aggregators.FLAT_CAPABLE)
 
 
+def _validate_arch(spec: spec_mod.ExperimentSpec) -> None:
+    """An architecture trains adapters on its frozen base, on token
+    data, through the sync engine."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.data.synthetic import TOKEN_SPECS
+    from repro.models.lora import TARGETS
+
+    model, data = spec.model, spec.data
+    if spec.regime.kind != "sync":
+        _err(f"model {model.name!r} runs through the sync engine only; got a "
+             f"{spec.regime.kind!r} regime")
+    if model.adapters is None:
+        _err(f"model {model.name!r} trains low-rank adapters on a frozen base: "
+             "set ModelSpec(adapters=AdapterSpec(...))")
+    ad = model.adapters
+    if ad.rank < 1 or ad.alpha <= 0:
+        _err(f"adapters need rank >= 1 and alpha > 0, got {ad.rank}, {ad.alpha}")
+    if not ad.targets or set(ad.targets) - set(TARGETS):
+        _err(f"adapter targets {list(ad.targets)} must be a non-empty subset of {list(TARGETS)}")
+    cfg = get_arch(model.name, smoke=model.smoke)
+    for key in model.overrides:
+        head, _, sub = key.partition(".")
+        owner = getattr(cfg, head, None) if sub else cfg
+        names = {f.name for f in dataclasses.fields(owner)} if dataclasses.is_dataclass(owner) else set()
+        if (sub or head) not in names:
+            _err(f"unknown ArchConfig override {key!r}")
+    if data.dataset not in TOKEN_SPECS:
+        _err(f"model {model.name!r} trains on a token dataset; have {sorted(TOKEN_SPECS)}")
+    if data.seq_len < 2:
+        _err(f"a token dataset needs seq_len >= 2, got {data.seq_len}")
+
+
 def validate(spec: spec_mod.ExperimentSpec, mesh=None) -> spec_mod.ExperimentSpec:
     """Checks ``spec`` against the live registries; returns it unchanged.
 
@@ -70,7 +104,9 @@ def validate(spec: spec_mod.ExperimentSpec, mesh=None) -> spec_mod.ExperimentSpe
     """
     from repro.adversary import engine as adversary_engine
     from repro.core import aggregators
+    from repro.configs import FL_ARCH_IDS
     from repro.data.synthetic import SPECS as DATASETS
+    from repro.data.synthetic import TOKEN_SPECS as TOKEN_DATASETS
     from repro.models import cnn
     from repro.stream import server as stream_server
     from repro.stream.events import LATENCIES
@@ -83,12 +119,20 @@ def validate(spec: spec_mod.ExperimentSpec, mesh=None) -> spec_mod.ExperimentSpe
     attack, trust, regime = spec.attack, spec.trust, spec.regime
 
     # ---- data / model names
-    datasets = set(DATASETS) | {SCENARIO_DATASET}
+    datasets = set(DATASETS) | set(TOKEN_DATASETS) | {SCENARIO_DATASET}
     if data.dataset not in datasets:
         _err(f"unknown dataset {data.dataset!r}; have {sorted(datasets)}")
-    models = set(cnn.MODELS) | {SCENARIO_MODEL}
+    models = set(cnn.MODELS) | set(FL_ARCH_IDS) | {SCENARIO_MODEL}
     if model.name not in models:
         _err(f"unknown model {model.name!r}; have {sorted(models)}")
+    if model.name in FL_ARCH_IDS:
+        _validate_arch(spec)
+    elif model.smoke or model.overrides or model.adapters is not None:
+        _err(f"model {model.name!r} is a CNN: smoke, overrides and adapters "
+             "apply to an architecture id")
+    elif data.dataset in TOKEN_DATASETS:
+        _err(f"token dataset {data.dataset!r} needs an architecture id; "
+             f"have {list(FL_ARCH_IDS)}")
     if data.n_workers < 1:
         _err(f"n_workers must be >= 1, got {data.n_workers}")
     if not 0.0 <= data.malicious_fraction <= 1.0:

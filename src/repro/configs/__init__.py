@@ -27,11 +27,20 @@ _ARCH_MODULES = {
 
 ARCH_IDS = tuple(_ARCH_MODULES)
 
+#: architectures run as federated clients (``repro.models.factory``),
+#: outside the dry-run grid
+_FL_ARCH_MODULES = {
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+}
+
+FL_ARCH_IDS = tuple(_FL_ARCH_MODULES)
+
 
 def get_arch(arch_id: str, smoke: bool = False) -> ArchConfig:
-    if arch_id not in _ARCH_MODULES:
-        raise KeyError(f"unknown arch {arch_id!r}; have {list(_ARCH_MODULES)}")
-    mod = importlib.import_module(f"repro.configs.{_ARCH_MODULES[arch_id]}")
+    modules = {**_ARCH_MODULES, **_FL_ARCH_MODULES}
+    if arch_id not in modules:
+        raise KeyError(f"unknown arch {arch_id!r}; have {list(modules)}")
+    mod = importlib.import_module(f"repro.configs.{modules[arch_id]}")
     return mod.smoke() if smoke else mod.CONFIG
 
 

@@ -26,6 +26,19 @@ class MoEConfig:
     # smaller groups cut MoE memory traffic at the cost of tighter
     # per-group capacity (more drops under load imbalance).  §Perf H2d.
     group_size: int = 512
+    # DeepSeek-V3 routing (the held dispatch): "sigmoid" scores, top-k
+    # chosen on score + a per-expert bias that takes no part in the
+    # weights, the chosen scores renormalised and scaled by routed_scaling
+    scoring: str = "softmax"  # softmax | sigmoid
+    selection_bias: bool = False
+    routed_scaling: float = 1.0
+    # expert parallelism: this device holds experts [expert_offset,
+    # expert_offset + experts_held) of n_experts; the router still scores
+    # all n_experts.  experts_held > 0 selects the dropless "held"
+    # dispatch (sort by expert, grouped products), which computes only
+    # the held experts' share of the layer's output
+    experts_held: int = 0
+    expert_offset: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +109,14 @@ class ArchConfig:
     frontend_dim: int = 0  # audio frame / vision patch embedding dim
     n_patches: int = 0  # vlm: image-prefix length in train/prefill shapes
     tied_embeddings: bool = True
+    norm_eps: float = 1e-6
+    # multi-head latent attention (DeepSeek-V2, no query compression):
+    # kv_lora_rank > 0 replaces the attention slot by MLA
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_k_dense: int = 0  # leading dense-MLP layers before the MoE stack
     source: str = ""  # citation
 
     @property

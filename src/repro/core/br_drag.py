@@ -144,7 +144,7 @@ def round_step_flat(
 ) -> tuple[pt.Pytree, dict, tuple]:
     """:func:`round_step` on the flat plane given the flat trusted r^t.
 
-    Returns (params', metrics, (dots, g_sq, r_sq))."""
+    Returns (params', metrics, (dots, g_sq, r_sq), lam [S])."""
     delta_flat, lams, stats = aggregate_flat(
         stack.data, reference_flat, c, discounts, weights, interpret=interpret
     )
@@ -155,7 +155,7 @@ def round_step_flat(
         "delta_norm": jnp.linalg.norm(delta_flat),
         "ref_norm": jnp.linalg.norm(reference_flat),
     }
-    return new_params, metrics, stats
+    return new_params, metrics, stats, lams
 
 
 def c_schedule(w: float, x: float) -> float:

@@ -14,7 +14,7 @@ import numpy as np
 
 from repro.core.attacks import flip_labels
 from repro.data.dirichlet import dirichlet_partition
-from repro.data.synthetic import SPECS, make_image_dataset
+from repro.data.synthetic import SPECS, TOKEN_SPECS, make_image_dataset, topic_sequences
 
 
 @dataclasses.dataclass
@@ -27,6 +27,7 @@ class FederatedData:
     malicious: np.ndarray  # bool [M] — workers under adversarial control
     attack: str = "none"  # none | noise_injection | sign_flipping | label_flipping
     flip_fraction: float = 0.5
+    root_pool: np.ndarray | None = None  # indices of a server-held root set
 
     def sample_round(self, rng: np.random.RandomState, selected, u: int, b: int):
         """Returns dict(x=[S,U,B,...], y=[S,U,B]) for the selected workers."""
@@ -35,7 +36,7 @@ class FederatedData:
             idx = self.parts[m]
             take = rng.choice(idx, size=u * b, replace=len(idx) < u * b)
             x = self.x[take].reshape(u, b, *self.x.shape[1:])
-            y = self.y[take].reshape(u, b).copy()
+            y = self.y[take].reshape(u, b, *self.y.shape[1:]).copy()
             if self.malicious[m] and self.attack == "label_flipping":
                 # label flipping on half the local samples (paper §VI-B),
                 # through the canonical transform in ``core.attacks`` so
@@ -49,13 +50,16 @@ class FederatedData:
 
     def root_batches(self, rng: np.random.RandomState, u: int, b: int, n_root: int):
         """Vetted root batches [U, B, ...] drawn from trusted (benign) data."""
-        benign = np.where(~self.malicious)[0]
-        pool = np.concatenate([self.parts[m] for m in benign])
-        pool = pool[: n_root] if len(pool) > n_root else pool
+        if self.root_pool is not None:
+            pool = self.root_pool
+        else:
+            benign = np.where(~self.malicious)[0]
+            pool = np.concatenate([self.parts[m] for m in benign])
+            pool = pool[: n_root] if len(pool) > n_root else pool
         take = rng.choice(pool, size=u * b, replace=len(pool) < u * b)
         return {
             "x": self.x[take].reshape(u, b, *self.x.shape[1:]),
-            "y": self.y[take].reshape(u, b).astype(np.int32),
+            "y": self.y[take].reshape(u, b, *self.y.shape[1:]).astype(np.int32),
         }
 
     def test_batch(self, n: int = 1024):
@@ -85,16 +89,22 @@ def build_federated_data(
     malicious_fraction: float = 0.0,
     attack: str = "none",
     seed: int = 0,
+    seq_len: int = 0,
+    vocab: int = 0,
+    root_samples: int = 0,
 ) -> FederatedData:
+    """A dataset split over ``n_workers`` by Dirichlet(beta) label skew.
+    A token dataset (``seq_len`` tokens of ``vocab`` ids per sequence)
+    splits by topic and holds a root set of ``root_samples`` sequences,
+    every topic equally."""
+    if dataset in TOKEN_SPECS:
+        return _token_data(TOKEN_SPECS[dataset], n_workers, beta, malicious_fraction,
+                           seed, seq_len, vocab, root_samples)
     spec = SPECS[dataset]
     data = make_image_dataset(spec, seed)
     x, y = data["train"]
     parts = dirichlet_partition(y, n_workers, beta, seed)
-    rng = np.random.RandomState(seed + 7)
-    malicious = np.zeros(n_workers, dtype=bool)
-    n_mal = int(round(malicious_fraction * n_workers))
-    if n_mal:
-        malicious[rng.choice(n_workers, size=n_mal, replace=False)] = True
+    malicious = _malicious(n_workers, malicious_fraction, seed)
     return FederatedData(
         x=x,
         y=y,
@@ -104,4 +114,33 @@ def build_federated_data(
         malicious=malicious,
         attack=attack,
         flip_fraction=0.5,
+    )
+
+
+def _malicious(n_workers: int, fraction: float, seed: int) -> np.ndarray:
+    rng = np.random.RandomState(seed + 7)
+    malicious = np.zeros(n_workers, dtype=bool)
+    n_mal = int(round(fraction * n_workers))
+    if n_mal:
+        malicious[rng.choice(n_workers, size=n_mal, replace=False)] = True
+    return malicious
+
+
+def _token_data(spec, n_workers, beta, malicious_fraction, seed, seq_len, vocab,
+                root_samples) -> FederatedData:
+    rng = np.random.RandomState(seed + 1)
+    train_topics = rng.randint(0, spec.n_topics, size=spec.n_train)
+    root_topics = np.arange(root_samples) % spec.n_topics
+    test_topics = np.arange(spec.n_test) % spec.n_topics
+    seqs = topic_sequences(rng, np.concatenate([train_topics, root_topics, test_topics]),
+                           seq_len, vocab, spec, seed)
+    n_fit = spec.n_train + root_samples
+    return FederatedData(
+        x=seqs[:n_fit, :-1],
+        y=seqs[:n_fit, 1:],
+        parts=dirichlet_partition(train_topics, n_workers, beta, seed),
+        test=(seqs[n_fit:, :-1], seqs[n_fit:, 1:]),
+        n_classes=vocab,
+        malicious=_malicious(n_workers, malicious_fraction, seed),
+        root_pool=np.arange(spec.n_train, n_fit),
     )

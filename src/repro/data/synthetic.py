@@ -71,7 +71,9 @@ def make_image_dataset(spec: ImageDatasetSpec, seed: int = 0):
 # ------------------------------------------------------------ token data
 
 def synth_token_batch(key, batch: int, seq: int, vocab: int):
-    """Synthetic LM batch with learnable structure: t_{i+1} depends on t_i."""
+    """Synthetic LM batch with learnable structure: t_{i+1} depends on t_i.
+    One ``lax.scan`` over the sequence, step i drawing from the i-th of
+    ``seq`` split keys."""
     k1, k2 = jax.random.split(key)
     first = jax.random.randint(k1, (batch, 1), 0, vocab)
 
@@ -79,12 +81,46 @@ def synth_token_batch(key, batch: int, seq: int, vocab: int):
         nxt = (tok * 31 + 17) % vocab
         noise = jax.random.bernoulli(k, 0.1, tok.shape)
         rand = jax.random.randint(k, tok.shape, 0, vocab)
-        return jnp.where(noise, rand, nxt)
+        tok = jnp.where(noise, rand, nxt)
+        return tok, tok
 
     keys = jax.random.split(k2, seq)
-    toks = [first]
-    for i in range(seq - 1):
-        toks.append(step(toks[-1], keys[i]))
-    tokens = jnp.concatenate(toks, axis=1)
+    _, rest = jax.lax.scan(step, first, keys[: seq - 1])  # [seq-1, batch, 1]
+    tokens = jnp.concatenate([first, jnp.moveaxis(rest[..., 0], 0, 1)], axis=1)
     targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
     return {"tokens": tokens, "targets": targets}
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenDatasetSpec:
+    """Sequences drawn from seeded topics: topic k continues token t with
+    (a_k t + c_k) mod V, and with probability ``noise`` with a uniform
+    id.  A client's topics follow the Dirichlet split, as image classes
+    do; the root set holds every topic equally."""
+
+    name: str
+    n_topics: int
+    n_train: int  # sequences
+    n_test: int
+    noise: float = 0.1
+
+
+TOPICS_SPEC = TokenDatasetSpec("topics", n_topics=8, n_train=1024, n_test=4)
+TOKEN_SPECS = {TOPICS_SPEC.name: TOPICS_SPEC}
+
+
+def topic_sequences(rng: np.random.RandomState, topics: np.ndarray, seq: int, vocab: int,
+                    spec: TokenDatasetSpec, seed: int = 0) -> np.ndarray:
+    """[N, seq + 1] int32 sequences, the n-th of topic ``topics[n]``
+    (topic maps from ``seed``, the draws from ``rng``)."""
+    maps = np.random.RandomState(seed + 3)
+    mult = 2 * maps.randint(1, vocab // 2, size=spec.n_topics) + 1
+    add = maps.randint(0, vocab, size=spec.n_topics)
+    a, c = mult[topics].astype(np.int64), add[topics].astype(np.int64)
+    out = np.empty((len(topics), seq + 1), np.int64)
+    out[:, 0] = rng.randint(0, vocab, size=len(topics))
+    noise = rng.rand(len(topics), seq) < spec.noise
+    rand = rng.randint(0, vocab, size=(len(topics), seq))
+    for i in range(seq):
+        out[:, i + 1] = np.where(noise[:, i], rand[:, i], (a * out[:, i] + c) % vocab)
+    return out.astype(np.int32)

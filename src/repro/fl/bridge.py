@@ -139,9 +139,10 @@ def streamed_round(
     if jit_client:
         client_fn = stream_server.make_client_fn(loss_fn, scfg)
     else:
-        from repro.fl.client import local_update
+        from repro.fl.client import local_update, with_counters
 
-        client_fn = lambda p, b: local_update(loss_fn, p, b, scfg.lr, variant="sgd")[0]
+        client_fn = lambda p, b: local_update(
+            with_counters(loss_fn), p, b, scfg.lr, variant="sgd")[0]
 
     es = EventStream(n_clients=max(s, 1), latency=Constant(0.0), seed=0)
     rnd_host = int(state.round)

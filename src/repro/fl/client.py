@@ -20,7 +20,12 @@ import jax.numpy as jnp
 
 from repro.core import pytree as pt
 
-LossFn = Callable[[object, dict], jax.Array]  # (params, batch) -> scalar
+LossFn = Callable[[object, dict], tuple]  # (params, batch) -> (scalar, counters)
+
+
+def with_counters(loss_fn: Callable) -> LossFn:
+    """A scalar loss in the form :func:`local_update` takes: no counters."""
+    return lambda params, batch: (loss_fn(params, batch), {})
 
 
 def local_update(
@@ -35,12 +40,18 @@ def local_update(
     control_global: pt.Pytree | None = None,  # scaffold h
     anchor: pt.Pytree | None = None,  # fedacg theta^{t-1} + lambda m^{t-1}
     beta: float = 0.2,  # fedacg
+    unroll: bool = True,
 ):
-    """Returns (g_m, aux) where aux carries variant-specific outputs."""
-    grad_fn = jax.grad(loss_fn)
+    """Returns (g_m, aux) where aux carries variant-specific outputs and
+    ``counters``: the loss's in-jit counters (an architecture's,
+    ``models/factory.py``; none for a scalar loss wrapped by
+    :func:`with_counters`), summed over the U steps.  ``unroll=False``
+    keeps the U steps one scanned body (large models, where unrolling
+    multiplies compile time)."""
+    grad_fn = jax.grad(loss_fn, has_aux=True)
 
     def step(theta, batch):
-        g = grad_fn(theta, batch)
+        g, counters = grad_fn(theta, batch)
         if variant == "fedprox":
             g = jax.tree.map(lambda gg, th, gl: gg + mu * (th - gl), g, theta, params_global)
         elif variant == "scaffold":
@@ -50,18 +61,18 @@ def local_update(
         elif variant == "fedacg":
             g = jax.tree.map(lambda gg, th, an: gg + beta * (th - an), g, theta, anchor)
         theta = jax.tree.map(lambda th, gg: th - lr * gg, theta, g)
-        return theta, None
+        return theta, counters
 
     # unroll=True: XLA:CPU executes while-loop bodies ~11x slower than
     # straight-line code (measured; see EXPERIMENTS.md §Perf notes), and U
     # is small and static in the paper's protocol (U=5).
-    theta_u, _ = jax.lax.scan(step, params_global, batches_u, unroll=True)
+    theta_u, counters = jax.lax.scan(step, params_global, batches_u, unroll=unroll)
     g_m = pt.tree_sub(theta_u, params_global)
 
-    aux = {}
+    aux = {"counters": jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)}
     if variant == "scaffold":
         # h_m^{t+1} = grad at the *start* point on the first batch (option II
         # of [13] simplified per the paper's §VI baseline description)
         first_batch = jax.tree.map(lambda x: x[0], batches_u)
-        aux["new_control"] = grad_fn(params_global, first_batch)
+        aux["new_control"] = grad_fn(params_global, first_batch)[0]
     return g_m, aux

@@ -23,7 +23,7 @@ from repro.adversary import engine as adversary_engine
 from repro.core import aggregators, br_drag, drag
 from repro.core import flat as flat_mod
 from repro.core import pytree as pt
-from repro.fl.client import local_update
+from repro.fl.client import local_update, with_counters
 from repro.trust import reputation as trust_mod
 
 
@@ -51,6 +51,11 @@ class RoundConfig:
     #   adds one extra pytree output from already-computed signals
     monitor: object = None  # obs.monitor.MonitorConfig | None — online
     #   change-point detectors over the bundle (requires telemetry=True)
+    model_kind: str = "cnn"  # models/factory.Model.kind.  "arch": the
+    #   round takes the frozen base as an argument, its loss returns
+    #   in-jit counters (reported in the metrics), and the S clients run
+    #   under lax.map with their U steps one scanned body (an unrolled
+    #   copy per client would multiply a large model's compile time by S)
 
 
 class ServerState(NamedTuple):
@@ -126,8 +131,12 @@ def _client_updates(loss_fn, state: ServerState, cfg: RoundConfig, batches, sele
         }.get(cfg.algorithm, "sgd")
         return local_update(
             loss_fn, state.params, batch_u, cfg.lr,
-            variant=variant, mu=cfg.mu, beta=cfg.acg_beta, **kw,
+            variant=variant, mu=cfg.mu, beta=cfg.acg_beta,
+            unroll=cfg.model_kind == "cnn", **kw,
         )
+
+    if cfg.model_kind == "arch":
+        return jax.lax.map(one, (batches, selected_idx))
 
     # NOTE: an unrolled python loop over the S selected workers, not vmap
     # and not lax.map — vmap batches the conv *filters* (each client's
@@ -139,11 +148,7 @@ def _client_updates(loss_fn, state: ServerState, cfg: RoundConfig, batches, sele
     s = jax.tree.leaves(batches)[0].shape[0]
     outs = [one((pt.tree_index(batches, i), selected_idx[i])) for i in range(s)]
     gs = pt.tree_stack([o[0] for o in outs])
-    aux = {}
-    if outs[0][1]:
-        aux = {
-            k: pt.tree_stack([o[1][k] for o in outs]) for k in outs[0][1]
-        }
+    aux = {k: pt.tree_stack([o[1][k] for o in outs]) for k in outs[0][1]}
     return gs, aux
 
 
@@ -156,9 +161,16 @@ def federated_round(
     malicious_mask,  # [S] bool
     key,
     root_batches=None,  # [U, B, ...] — BR-DRAG / FLTrust root data
+    frozen=None,  # the frozen base of an architecture's adapters
 ) -> tuple[ServerState, dict]:
     s = malicious_mask.shape[0]
-    g_stacked, aux = _client_updates(loss_fn, state, cfg, batches, selected_idx)
+    if cfg.model_kind == "arch":
+        loss = partial(loss_fn, base=frozen)  # (loss, counters)
+    else:
+        loss = with_counters(loss_fn)
+
+    g_stacked, aux = _client_updates(loss, state, cfg, batches, selected_idx)
+    counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), aux.pop("counters"))
 
     # ---- THE flatten boundary (repro.core.flat): the S uploads enter
     # the flat [S, d] update plane here and stay flat through attack
@@ -230,14 +242,16 @@ def federated_round(
             )
     elif cfg.algorithm in ("br_drag", "fltrust"):
         assert root_batches is not None, f"{cfg.algorithm} needs a root dataset"
-        grad_fn = jax.grad(loss_fn)
-        reference = br_drag.root_reference(params, lambda p, b: grad_fn(p, b), root_batches, cfg.lr)
+        # the root trains as a client does: r^t = theta^{t,U} - theta^t
+        reference, root_aux = local_update(loss, params, root_batches, cfg.lr,
+                                           unroll=cfg.model_kind == "cnn")
+        counters = jax.tree.map(jnp.add, counters, root_aux["counters"])
         r_flat = flat_mod.flatten_tree(reference)
         if cfg.algorithm == "br_drag":
-            params, dm, stats = br_drag.round_step_flat(
+            params, dm, stats, lams = br_drag.round_step_flat(
                 params, stack, r_flat, c=cfg.c_br, weights=weights
             )
-            metrics.update(dm)
+            metrics.update(dm, dod=lams)
             update_norms = jnp.sqrt(stats[1])
             stats_obs = stats
             if use_trust:
@@ -286,6 +300,8 @@ def federated_round(
     if update_norms is None:
         update_norms = jnp.linalg.norm(stack.data, axis=1)
     metrics["update_norm_mean"] = jnp.mean(update_norms)
+    # in-jit counters of every client's and the root's training steps
+    metrics.update(counters)
     if cfg.telemetry:
         from repro.obs import metrics as obs_metrics
 
@@ -323,20 +339,21 @@ def federated_round(
 
 
 def make_round_fn(loss_fn, cfg: RoundConfig, with_root: bool):
-    """jit-compiled round with static config."""
+    """jit-compiled round with static config: ``fn(state, batches,
+    selected_idx, malicious_mask, key[, root_batches][, frozen])``, the
+    root batches with ``with_root``, the frozen base for an
+    architecture (``cfg.model_kind == "arch"``)."""
+    n_extra = int(with_root) + int(cfg.model_kind == "arch")
 
-    if with_root:
-        @partial(jax.jit, donate_argnums=(0,))
-        def fn(state, batches, selected_idx, malicious_mask, key, root_batches):
-            return federated_round(
-                loss_fn, state, cfg, batches, selected_idx, malicious_mask, key,
-                root_batches=root_batches,
-            )
-    else:
-        @partial(jax.jit, donate_argnums=(0,))
-        def fn(state, batches, selected_idx, malicious_mask, key):
-            return federated_round(
-                loss_fn, state, cfg, batches, selected_idx, malicious_mask, key
-            )
+    @partial(jax.jit, donate_argnums=(0,))
+    def fn(state, batches, selected_idx, malicious_mask, key, *extra):
+        if len(extra) != n_extra:
+            raise TypeError(f"the round takes {n_extra} argument(s) after the key, "
+                            f"got {len(extra)}")
+        return federated_round(
+            loss_fn, state, cfg, batches, selected_idx, malicious_mask, key,
+            root_batches=extra[0] if with_root else None,
+            frozen=extra[-1] if cfg.model_kind == "arch" else None,
+        )
 
     return fn

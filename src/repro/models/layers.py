@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +36,23 @@ def _split(key, n):
 def dense_init(key, in_dim, out_dim, dtype, scale=None):
     scale = scale if scale is not None else 1.0 / math.sqrt(in_dim)
     return (jax.random.normal(key, (in_dim, out_dim)) * scale).astype(dtype)
+
+
+class Adapted(NamedTuple):
+    """A frozen weight with its low-rank adapter (LoRA, Hu et al., arXiv
+    2106.09685): ``linear`` computes ``x W + (x A) B``; ``b`` arrives
+    already scaled by alpha / rank."""
+
+    w: jax.Array
+    a: jax.Array
+    b: jax.Array
+
+
+def linear(x, w):
+    """``x @ w`` over the last axis, for a plain or an adapted weight."""
+    if isinstance(w, Adapted):
+        return linear(x, w.w) + linear(linear(x, w.a), w.b)
+    return jnp.einsum("...d,df->...f", x, w)
 
 
 def rms_norm(x, weight, eps: float = 1e-6):
@@ -150,14 +168,15 @@ def multihead_attention(
     n_rep = h // k.shape[2]
     k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
     skv = k.shape[1]
+    d = v.shape[-1]  # the output's head size (MLA's v is narrower than q/k)
 
     if sq == 1:  # decode fast-path: single query against whole cache view
         return _attend(q, k, v, q_pos, kv_pos, kind=kind, window=window)
 
     if kind == "chunk" and window > 0 and sq % window == 0 and sq == skv:
         nc = sq // window
-        qc = q.reshape(b * nc, window, h, d)
-        kc = k.reshape(b * nc, window, h, d)
+        qc = q.reshape(b * nc, window, h, q.shape[-1])
+        kc = k.reshape(b * nc, window, h, k.shape[-1])
         vc = v.reshape(b * nc, window, h, d)
         qp = q_pos.reshape(b * nc, window)
         kp = kv_pos.reshape(b * nc, window)
@@ -334,6 +353,86 @@ def init_attn_cache(cfg: AttnConfig, batch: int, cache_len: int, dtype):
     }
 
 
+# ------------------------------------------------- multi-head latent attention
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    q_block: int = 1024
+    impl: str = "xla"  # "flash": the Pallas TPU flash-attention kernel on a TPU
+
+
+def init_mla(key, cfg: MLAConfig, dtype):
+    ks = _split(key, 4)
+    h, dq = cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return {
+        "wq": dense_init(ks[0], cfg.d_model, h * dq, dtype),
+        "wkv_a": dense_init(ks[1], cfg.d_model, cfg.kv_lora_rank + cfg.qk_rope_head_dim, dtype),
+        "kv_norm": jnp.ones((cfg.kv_lora_rank,), dtype),
+        "wkv_b": dense_init(ks[2], cfg.kv_lora_rank,
+                            h * (cfg.qk_nope_head_dim + cfg.v_head_dim), dtype),
+        "wo": dense_init(ks[3], h * cfg.v_head_dim, cfg.d_model, dtype),
+    }
+
+
+def mla_block(params, cfg: MLAConfig, x, positions):
+    """DeepSeek-V2 multi-head latent attention without query compression,
+    training form (the full keys and values, no latent cache).
+    x: [B, S, d_model] -> [B, S, d_model].
+
+    q = x W_q -> [q_nope, q_rope] per head; [c_kv, k_rope] = x W_kva with
+    c_kv RMS-normed; [k_nope, v] = c_kv W_kvb per head; RoPE on q_rope
+    and on the one k_rope all heads share; causal softmax of
+    (q_nope.k_nope + q_rope.k_rope) / sqrt(nope + rope); out = W_o v."""
+    b, s, _ = x.shape
+    h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = linear(x, params["wq"]).reshape(b, s, h, dn + dr)
+    kv_a = linear(x, params["wkv_a"])
+    c_kv = rms_norm(kv_a[..., : cfg.kv_lora_rank], params["kv_norm"], cfg.norm_eps)
+    k_rope = apply_rope(kv_a[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)
+    kv = linear(c_kv, params["wkv_b"]).reshape(b, s, h, dn + dv)
+    q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta)], -1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], -1)
+    if cfg.impl == "flash" and jax.default_backend() == "tpu":
+        out = _tpu_flash_causal(q, k, kv[..., dn:])
+    else:
+        out = multihead_attention(q, k, kv[..., dn:], positions, positions,
+                                  kind="causal", q_block=cfg.q_block)
+    return linear(out.reshape(b, s, h * dv), params["wo"])
+
+
+def _tpu_flash_causal(q, k, v, block: int = 512):
+    """Causal attention through JAX's Pallas TPU flash-attention kernel
+    (forward and backward; positions 0..S-1).  The kernel takes one head
+    size for q, k and v, a multiple of 128: all three are zero-padded to
+    it, which leaves the scores and the first ``v`` columns of the output
+    as they are.  Inputs go in as bfloat16, the rounding a default-precision
+    f32 product on the TPU applies to its inputs."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    dv = v.shape[-1]
+    d = -(-max(q.shape[-1], dv) // 128) * 128
+    bq = min(block, q.shape[1])
+
+    def heads_first(t):
+        t = jnp.pad(t, [(0, 0)] * 3 + [(0, d - t.shape[-1])])
+        return t.astype(jnp.bfloat16).transpose(0, 2, 1, 3)
+
+    sizes = fa.BlockSizes(block_q=bq, block_k_major=bq, block_k=bq, block_b=1,
+                          block_q_major_dkv=bq, block_k_major_dkv=bq, block_k_dkv=bq,
+                          block_q_dkv=bq, block_k_major_dq=bq, block_k_dq=bq, block_q_dq=bq)
+    out = fa.flash_attention(heads_first(q), heads_first(k), heads_first(v), causal=True,
+                             sm_scale=q.shape[-1] ** -0.5, block_sizes=sizes)
+    return out.transpose(0, 2, 1, 3)[..., :dv].astype(q.dtype)
+
+
 # ------------------------------------------------------------------- MLP
 
 def init_swiglu(key, d_model, d_ff, dtype):
@@ -346,10 +445,10 @@ def init_swiglu(key, d_model, d_ff, dtype):
 
 
 def swiglu(params, x, shard=lambda t, name: t):
-    g = jnp.einsum("bsd,df->bsf", x, params["w_gate"])
-    u = jnp.einsum("bsd,df->bsf", x, params["w_up"])
+    g = linear(x, params["w_gate"])
+    u = linear(x, params["w_up"])
     h = shard(jax.nn.silu(g) * u, "act_ff")
-    return shard(jnp.einsum("bsf,fd->bsd", h, params["w_down"]), "act_model")
+    return shard(linear(h, params["w_down"]), "act_model")
 
 
 def init_gelu_mlp(key, d_model, d_ff, dtype):

@@ -1,6 +1,11 @@
-"""Mixture-of-Experts MLP with expert parallelism (Llama-4-Scout, Kimi-K2).
+"""Mixture-of-Experts MLP with expert parallelism (Llama-4-Scout, Kimi-K2,
+Moonlight).
 
-Two dispatch strategies, selectable via ``MoEConfig.dispatch``:
+A layer told which experts it holds (``MoEConfig.experts_held`` > 0)
+routes over all of them (:func:`route`, DeepSeek-V3's sigmoid scores and
+selection-only bias) and computes its own experts' share of the output,
+dropless: ``_dispatch_held``.  Otherwise, two capacity dispatch
+strategies, selectable via ``MoEConfig.dispatch``:
 
   * ``einsum`` — classic capacity-based one-hot dispatch/combine einsums
     (Switch/GShard style).  Tokens are partitioned into *groups* so the
@@ -19,7 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, linear
 
 GROUP_SIZE = 1024  # tokens per dispatch group (einsum mode)
 
@@ -27,19 +32,22 @@ GROUP_SIZE = 1024  # tokens per dispatch group (einsum mode)
 def init_moe(key, cfg, dtype):
     d, e = cfg.d_model, cfg.moe
     ks = jax.random.split(key, 5)
+    n_local = e.experts_held or e.n_experts  # the experts this device holds
     p = {
         "router": dense_init(ks[0], d, e.n_experts, jnp.float32),
         "w_gate": (
-            jax.random.normal(ks[1], (e.n_experts, d, e.d_ff_expert)) / d**0.5
+            jax.random.normal(ks[1], (n_local, d, e.d_ff_expert)) / d**0.5
         ).astype(dtype),
         "w_up": (
-            jax.random.normal(ks[2], (e.n_experts, d, e.d_ff_expert)) / d**0.5
+            jax.random.normal(ks[2], (n_local, d, e.d_ff_expert)) / d**0.5
         ).astype(dtype),
         "w_down": (
-            jax.random.normal(ks[3], (e.n_experts, e.d_ff_expert, d))
+            jax.random.normal(ks[3], (n_local, e.d_ff_expert, d))
             / e.d_ff_expert**0.5
         ).astype(dtype),
     }
+    if e.selection_bias:
+        p["router_bias"] = jnp.zeros((e.n_experts,), jnp.float32)
     if e.n_shared_experts:
         dsh = e.d_ff_expert * e.n_shared_experts
         k1, k2, k3 = jax.random.split(ks[4], 3)
@@ -64,6 +72,59 @@ def _router(params, cfg, x):
     p_mean = jnp.mean(probs, axis=0)
     aux = e.n_experts * jnp.sum(f * p_mean)
     return probs, topk_idx, topk_w, aux
+
+
+def route(params, cfg, x):
+    """DeepSeek-V3 routing.  x: [T, d] -> (topk_idx [T, k], topk_w [T, k]).
+
+    Scores s = sigmoid(x W_r) (or softmax) over all experts; the top k
+    are chosen on s + b (the selection bias, when the layer has one) and
+    weighted by their own scores, renormalised to sum to one and scaled
+    by ``routed_scaling``."""
+    e = cfg.moe
+    # full float32, as the published gate computes it: at one bf16 pass
+    # the scores of near-tied experts swap and tokens change expert
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), params["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits) if e.scoring == "sigmoid" else jax.nn.softmax(logits, -1)
+    choice = scores + params["router_bias"] if e.selection_bias else scores
+    _, topk_idx = jax.lax.top_k(choice, e.top_k)
+    topk_w = jnp.take_along_axis(scores, topk_idx, axis=-1)
+    topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
+    return topk_idx, topk_w * e.routed_scaling
+
+
+def _dispatch_held(params, cfg, x):
+    """Dropless dispatch onto the experts this device holds.  x: [T, d].
+
+    Every (token, choice) assignment is sorted by its expert, those of
+    the held experts first and in expert order, and each held expert's
+    rows go through its SwiGLU as one group of a grouped product
+    (``lax.ragged_dot``), however many there are: the group sizes are
+    the count of held assignments and the row buffer holds every (token,
+    choice) row, so nothing can be dropped.  Returns the held experts'
+    weighted share of the output and the assignments computed per held
+    expert."""
+    e = cfg.moe
+    t, d = x.shape
+    held = e.experts_held
+    topk_idx, topk_w = route(params, cfg, x)
+    local = topk_idx.reshape(-1) - e.expert_offset
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)
+    order = jnp.argsort(group, stable=True)
+    tok = order // e.top_k
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    # rows past the held groups are left unwritten by the TPU's grouped
+    # product, in its output and in its input gradient: mask both ends
+    rows = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(rows, x[tok], 0.0)
+    g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
+    out = jax.lax.ragged_dot(jax.nn.silu(g) * u, params["w_down"], sizes)
+    w = jnp.where(mine, topk_w.reshape(-1), 0.0)[order].astype(x.dtype)
+    y = jnp.zeros((t, d), x.dtype).at[tok].add(jnp.where(rows, out * w[:, None], 0.0))
+    return y, {"assignments": sizes}
 
 
 def _experts_ffn(params, h_in):
@@ -146,17 +207,23 @@ def _dispatch_sort(params, cfg, x, shard):
 
 
 def moe_mlp(params, cfg, x, shard=lambda t, n: t):
-    """x: [B, S, d] -> ([B, S, d], aux_loss)."""
+    """x: [B, S, d] -> ([B, S, d], aux_loss, counters).  ``counters``
+    (assignments per held expert) come from the held dispatch; the
+    capacity dispatches return none."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
-    if cfg.moe.dispatch == "sort":
+    counts = {}
+    if cfg.moe.experts_held:
+        y, counts = _dispatch_held(params, cfg, xt)
+        aux = jnp.float32(0.0)
+    elif cfg.moe.dispatch == "sort":
         y, aux = _dispatch_sort(params, cfg, xt, shard)
     else:
         y, aux = _dispatch_einsum(params, cfg, xt, shard)
     y = y.reshape(b, s, d)
     if cfg.moe.n_shared_experts:
         sh = params["shared"]
-        g = jnp.einsum("bsd,df->bsf", x, sh["w_gate"])
-        u = jnp.einsum("bsd,df->bsf", x, sh["w_up"])
-        y = y + jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, sh["w_down"])
-    return shard(y, "act_model"), aux
+        g = linear(x, sh["w_gate"])
+        u = linear(x, sh["w_up"])
+        y = y + linear(jax.nn.silu(g) * u, sh["w_down"])
+    return shard(y, "act_model"), aux, counts

@@ -12,7 +12,10 @@ once regardless of depth:
   * ssm (mamba)         : P=1, slot = [mamba] (no mlp)
 
 Each slot owns its pre-norms; params for a slot are stacked with a
-leading ``n_blocks`` axis and consumed by ``lax.scan``.
+leading ``n_blocks`` axis and consumed by ``lax.scan``.  An MoE model
+with ``first_k_dense`` leading dense-MLP layers (DeepSeek-V3, Moonlight)
+runs them as a stack of their own (``params["dense"]``) before the MoE
+stack; ``kv_lora_rank`` > 0 makes the attention slot MLA.
 """
 from __future__ import annotations
 
@@ -37,10 +40,11 @@ _identity_shard: ShardFn = lambda t, name: t
 
 @dataclasses.dataclass(frozen=True)
 class SlotSpec:
-    mixer: str  # attn | mamba | rglru
+    mixer: str  # attn | mla | mamba | rglru
     attn_kind: str = "causal"
     use_rope: bool = True
     has_mlp: bool = True
+    dense: bool = False  # a leading dense-MLP layer of an MoE model
 
 
 def pattern_of(cfg: ArchConfig) -> tuple[list[SlotSpec], list[SlotSpec]]:
@@ -65,8 +69,32 @@ def pattern_of(cfg: ArchConfig) -> tuple[list[SlotSpec], list[SlotSpec]]:
         ] + [SlotSpec("attn", attn_kind="causal", use_rope=False)]  # NoPE global
         assert cfg.n_layers % p == 0
         return slots, []
+    if cfg.kv_lora_rank:
+        return [SlotSpec("mla")], []
     kind = "full" if cfg.arch_type == "audio" else cfg.attn_kind
     return [SlotSpec("attn", attn_kind=kind)], []
+
+
+def dense_slots(cfg: ArchConfig) -> list[SlotSpec]:
+    """The leading dense-MLP layers (``first_k_dense``), run once each
+    before the scanned stack."""
+    pattern, _ = pattern_of(cfg)
+    return [dataclasses.replace(pattern[0], dense=True)] if cfg.first_k_dense else []
+
+
+def mla_config(cfg: ArchConfig) -> L.MLAConfig:
+    return L.MLAConfig(
+        d_model=cfg.d_model,
+        n_heads=cfg.n_heads,
+        kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim,
+        v_head_dim=cfg.v_head_dim,
+        rope_theta=cfg.rope_theta,
+        norm_eps=cfg.norm_eps,
+        q_block=cfg.q_block,
+        impl=cfg.attn_impl,
+    )
 
 
 def attn_config(cfg: ArchConfig, spec: SlotSpec) -> L.AttnConfig:
@@ -95,6 +123,8 @@ def _init_slot(key, cfg: ArchConfig, spec: SlotSpec, dtype):
         p["norm1_b"] = jnp.zeros((cfg.d_model,), dtype)
     if spec.mixer == "attn":
         p["attn"] = L.init_attention(ks[0], attn_config(cfg, spec), dtype)
+    elif spec.mixer == "mla":
+        p["attn"] = L.init_mla(ks[0], mla_config(cfg), dtype)
     elif spec.mixer == "mamba":
         p["mamba"] = M.init_mamba(ks[0], cfg, dtype)
     else:
@@ -103,7 +133,7 @@ def _init_slot(key, cfg: ArchConfig, spec: SlotSpec, dtype):
         p["norm2"] = jnp.ones((cfg.d_model,), dtype)
         if cfg.norm == "layernorm":
             p["norm2_b"] = jnp.zeros((cfg.d_model,), dtype)
-        if cfg.arch_type == "moe":
+        if cfg.arch_type == "moe" and not spec.dense:
             p["mlp"] = MOE.init_moe(ks[1], cfg, dtype)
         elif cfg.mlp == "gelu":
             p["mlp"] = L.init_gelu_mlp(ks[1], cfg.d_model, cfg.d_ff, dtype)
@@ -115,7 +145,7 @@ def _init_slot(key, cfg: ArchConfig, spec: SlotSpec, dtype):
 def init_params(key, cfg: ArchConfig, dtype=jnp.float32):
     pattern, tail = pattern_of(cfg)
     p_len = len(pattern)
-    n_blocks = cfg.n_layers // p_len
+    n_blocks = (cfg.n_layers - cfg.first_k_dense) // p_len
     keys = jax.random.split(key, 8)
 
     params: dict = {}
@@ -141,6 +171,8 @@ def init_params(key, cfg: ArchConfig, dtype=jnp.float32):
             )(ks)
         return out
 
+    if cfg.first_k_dense:
+        params["dense"] = init_stack(keys[4], dense_slots(cfg), cfg.first_k_dense)
     params["stack"] = init_stack(keys[2], pattern, n_blocks)
     if tail:
         params["tail"] = init_stack(keys[3], tail, 1)
@@ -176,63 +208,67 @@ def init_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype=jnp.float32):
 
 # -------------------------------------------------------------- forward
 
-def _norm(x, w, b, kind):
-    return L.layer_norm(x, w, b) if kind == "layernorm" else L.rms_norm(x, w)
+def _norm(x, w, b, cfg: ArchConfig):
+    if cfg.norm == "layernorm":
+        return L.layer_norm(x, w, b)
+    return L.rms_norm(x, w, cfg.norm_eps)
 
 
 def _apply_slot(p, cfg: ArchConfig, spec: SlotSpec, x, positions, cache, shard):
-    h = _norm(x, p["norm1"], p.get("norm1_b"), cfg.norm)
+    h = _norm(x, p["norm1"], p.get("norm1_b"), cfg)
     if spec.mixer == "attn":
         out, new_cache = L.attention_block(
             p["attn"], attn_config(cfg, spec), h, positions, cache, shard
         )
+    elif spec.mixer == "mla":
+        if cache is not None:
+            raise NotImplementedError("MLA runs in training form only (no cache)")
+        out, new_cache = L.mla_block(p["attn"], mla_config(cfg), h, positions), None
     elif spec.mixer == "mamba":
         out, new_cache = M.mamba_mixer(p["mamba"], cfg, h, cache, shard)
     else:
         out, new_cache = R.rglru_mixer(p["rglru"], cfg, h, cache, shard)
     x = x + out
-    aux = jnp.float32(0.0)
+    aux, counts = jnp.float32(0.0), {}
     if spec.has_mlp:
-        h = _norm(x, p["norm2"], p.get("norm2_b"), cfg.norm)
-        if cfg.arch_type == "moe":
-            out, aux = MOE.moe_mlp(p["mlp"], cfg, h, shard)
+        h = _norm(x, p["norm2"], p.get("norm2_b"), cfg)
+        if cfg.arch_type == "moe" and not spec.dense:
+            out, aux, counts = MOE.moe_mlp(p["mlp"], cfg, h, shard)
         elif cfg.mlp == "gelu":
             out = L.gelu_mlp(p["mlp"], h, shard)
         else:
             out = L.swiglu(p["mlp"], h, shard)
         x = x + out
-    return x, new_cache, aux
+    return x, new_cache, aux, counts
 
 
 def _run_stack(stack_params, slots, cfg, x, positions, stack_cache, shard, remat):
-    """Scan a pattern stack.  Caches (if present) are scanned alongside."""
+    """Scan a pattern stack.  Caches (if present) are scanned alongside.
+    Returns (x, new caches, summed aux loss, counters per block and slot)."""
 
     def block(x, per_block):
         bp, bc = per_block
         aux_total = jnp.float32(0.0)
-        new_bc = {}
+        new_bc, counts = {}, {}
         for j, spec in enumerate(slots):
             sc = bc.get(f"slot{j}") if bc is not None else None
-            x, nc, aux = _apply_slot(bp[f"slot{j}"], cfg, spec, x, positions, sc, shard)
+            x, nc, aux, cnt = _apply_slot(bp[f"slot{j}"], cfg, spec, x, positions, sc, shard)
             if nc is not None:
                 new_bc[f"slot{j}"] = nc
+            if cnt:
+                counts[f"slot{j}"] = cnt
             aux_total = aux_total + aux
         x = shard(x, "act_model")
-        return x, (new_bc if new_bc else None, aux_total)
+        return x, (new_bc if new_bc else None, aux_total, counts)
 
     if remat:
         block = jax.checkpoint(block)
 
-    def scan_body(carry, per_block):
-        x = carry
-        x, (nc, aux) = block(x, per_block)
-        return x, (nc, aux)
-
     xs = (stack_params, stack_cache)
     # cfg.q_unroll doubles as "cost-analysis mode": fully unroll the layer
     # scan so XLA cost analysis (which counts while bodies once) is exact.
-    x, (new_caches, auxes) = jax.lax.scan(scan_body, x, xs, unroll=bool(cfg.q_unroll))
-    return x, new_caches, jnp.sum(auxes)
+    x, (new_caches, auxes, counts) = jax.lax.scan(block, x, xs, unroll=bool(cfg.q_unroll))
+    return x, new_caches, jnp.sum(auxes), counts
 
 
 def forward(
@@ -248,6 +284,28 @@ def forward(
     remat: bool = False,
 ):
     """Returns (logits [B,S,V], new_cache, aux_loss)."""
+    return forward_counted(
+        params, cfg, tokens, positions=positions, embeds=embeds,
+        patch_embeds=patch_embeds, cache=cache, shard=shard, remat=remat,
+    )[:3]
+
+
+def forward_counted(
+    params,
+    cfg: ArchConfig,
+    tokens: Optional[jax.Array] = None,
+    *,
+    positions: Optional[jax.Array] = None,
+    embeds: Optional[jax.Array] = None,
+    patch_embeds: Optional[jax.Array] = None,
+    cache=None,
+    shard: ShardFn = _identity_shard,
+    remat: bool = False,
+):
+    """:func:`forward` plus the MoE layers' counters: (logits, new_cache,
+    aux_loss, counts), ``counts`` holding per scanned stack and slot the
+    held dispatch's ``assignments`` [n_blocks, experts_held] (empty for
+    the capacity dispatches)."""
     pattern, tail = pattern_of(cfg)
 
     if cfg.arch_type == "audio":
@@ -269,14 +327,18 @@ def forward(
     x = shard(x, "act_model")
 
     new_cache: dict = {}
-    x, nc, aux = _run_stack(
+    counts: dict = {}
+    if cfg.first_k_dense:
+        x, _, _, _ = _run_stack(params["dense"], dense_slots(cfg), cfg, x, positions,
+                                None, shard, remat)
+    x, nc, aux, counts["stack"] = _run_stack(
         params["stack"], pattern, cfg, x, positions,
         cache["stack"] if cache is not None else None, shard, remat,
     )
     if nc is not None:
         new_cache["stack"] = nc
     if tail:
-        x, nct, aux_t = _run_stack(
+        x, nct, aux_t, counts["tail"] = _run_stack(
             params["tail"], tail, cfg, x, positions,
             cache["tail"] if cache is not None else None, shard, remat,
         )
@@ -284,13 +346,13 @@ def forward(
         if nct is not None:
             new_cache["tail"] = nct
 
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
     if cfg.tied_embeddings:
         logits = L.unembed(params["embed"], x)
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
     logits = shard(logits, "act_vocab")
-    return logits, (new_cache if cache is not None else None), aux
+    return logits, (new_cache if cache is not None else None), aux, counts
 
 
 # ----------------------------------------------------------------- loss

@@ -53,7 +53,7 @@ from repro.core import br_drag
 from repro.core import flat as flat_mod
 from repro.core import pytree as pt
 from repro.core.attacks import flip_labels
-from repro.fl.client import local_update
+from repro.fl.client import local_update, with_counters
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.stream import buffer as buf_mod
@@ -183,7 +183,7 @@ def make_megastep(loss_fn, cfg, dd: DeviceData, *, seed, n_clients: int,
 
     def client_row(spec, row, bx, by):
         g, _ = local_update(
-            loss_fn, flat_mod.unflatten_tree(row, spec), {"x": bx, "y": by},
+            with_counters(loss_fn), flat_mod.unflatten_tree(row, spec), {"x": bx, "y": by},
             cfg.lr, variant="sgd",
         )
         return flat_mod.flatten_tree(g)

@@ -49,7 +49,7 @@ from repro.adversary import engine as adversary_engine
 from repro.core import aggregators, br_drag, drag
 from repro.core import flat as flat_mod
 from repro.core import pytree as pt
-from repro.fl.client import local_update
+from repro.fl.client import local_update, with_counters
 from repro.obs import metrics as obs_metrics
 from repro.obs import monitor as obs_monitor
 from repro.obs import session as obs_session
@@ -254,7 +254,7 @@ def flush(
             )
         r_flat = flat_mod.flatten_tree(reference)
         if cfg.algorithm == "br_drag":
-            params, dm, stats = br_drag.round_step_flat(
+            params, dm, stats, _ = br_drag.round_step_flat(
                 params, stack, r_flat, c=cfg.c_br, discounts=discounts,
                 weights=weights,
             )
@@ -560,7 +560,7 @@ def make_client_fn(loss_fn: Callable, cfg: StreamConfig):
     scaffold/fedacg stay in the synchronous regime)."""
 
     def fn(params, batches_u):
-        g, _ = local_update(loss_fn, params, batches_u, cfg.lr, variant="sgd")
+        g, _ = local_update(with_counters(loss_fn), params, batches_u, cfg.lr, variant="sgd")
         return g
 
     return jax.jit(fn)
@@ -813,7 +813,7 @@ def run_stream_experiment(
     from repro.api import lowering
     from repro.api.validation import ensure_executable, validate
     from repro.data.pipeline import build_federated_data
-    from repro.models import cnn
+    from repro.models import factory
 
     spec = lowering.as_spec(exp)
     if spec.regime.kind not in ("async", "sharded"):
@@ -837,16 +837,10 @@ def run_stream_experiment(
             seed=spec.seed,
         )
 
-    init_fn, apply_fn = cnn.MODELS[spec.model.name]
+    model = factory.build(spec.model)
     key, k_init = jax.random.split(key)
-    if spec.model.name == "mlp":
-        in_dim = int(np.prod(data.x.shape[1:]))
-        params = init_fn(k_init, in_dim, 64, data.n_classes)
-    else:
-        params = init_fn(k_init)
-
-    def loss_fn(p, batch):
-        return cnn.classification_loss(apply_fn, p, batch)
+    _, params = model.init(k_init, data)
+    loss_fn = model.loss
 
     # THE async lowering (repro.api.lowering): spec -> static flush config.
     # label_flipping resolves to a data-space passthrough in the adversary
@@ -869,7 +863,7 @@ def run_stream_experiment(
 
     drift_on = d.drift != "none" and d.drift_rate > 0.0
 
-    eval_jit = jax.jit(lambda p, b: cnn.accuracy(apply_fn, p, b))
+    eval_jit = jax.jit(model.accuracy)
     tb = data.test_batch()
     test_x = jnp.asarray(tb["x"])
     test_batch = {"x": test_x, "y": jnp.asarray(tb["y"])}

@@ -35,7 +35,7 @@ from repro.api import lowering
 from repro.api.validation import ensure_executable, validate
 from repro.data.pipeline import build_federated_data, drift_labels
 from repro.fl.round import federated_round, init_server_state
-from repro.models import cnn
+from repro.models import factory
 from repro.obs import trace as obs_trace
 from repro.sweep import cache as cache_mod
 from repro.sweep import grouping
@@ -53,12 +53,11 @@ class SyncGroupExecutable:
     def __init__(self, spec):
         self.cfg = lowering.round_config(spec)
         self.with_root = self.cfg.algorithm in ("br_drag", "fltrust")
-        self.model = spec.model.name
-        init_fn, apply_fn = cnn.MODELS[self.model]
-        self.init_fn = init_fn
-
-        def loss_fn(p, batch):
-            return cnn.classification_loss(apply_fn, p, batch)
+        self.model = factory.build(spec.model)
+        if self.model.kind != "cnn":
+            raise ValueError(f"sweep groups batch CNN clients; {spec.model.name!r} "
+                             "runs through repro.fl.run_experiment")
+        loss_fn = self.model.loss
 
         cfg = self.cfg
         if self.with_root:
@@ -71,9 +70,7 @@ class SyncGroupExecutable:
             self.round_fn = jax.jit(jax.vmap(
                 lambda st, b, s, m, k: federated_round(loss_fn, st, cfg, b, s, m, k)
             ))
-        self.eval_fn = jax.jit(jax.vmap(
-            lambda p, b: cnn.accuracy(apply_fn, p, b)
-        ))
+        self.eval_fn = jax.jit(jax.vmap(self.model.accuracy))
 
     # ------------------------------------------------------------- members
     def _prime_member(self, spec, cfg):
@@ -89,11 +86,7 @@ class SyncGroupExecutable:
             seed=spec.seed,
         )
         key, k_init = jax.random.split(key)
-        if self.model == "mlp":
-            in_dim = int(np.prod(data.x.shape[1:]))
-            params = self.init_fn(k_init, in_dim, 64, data.n_classes)
-        else:
-            params = self.init_fn(k_init)
+        _, params = self.model.init(k_init, data)
         state = init_server_state(params, d.n_workers, cfg)
         return {"spec": spec, "rng": rng, "key": key, "data": data, "state": state}
 

@@ -45,6 +45,43 @@ class TestSynthetic:
         assert b["targets"].shape == (4, 32)
         assert int(b["tokens"].max()) < 101
 
+    @pytest.mark.parametrize("seq", [1, 2, 17])
+    def test_token_batch_scan_equals_the_step_loop(self, seq):
+        """The scanned sequence is the one the per-position loop draws
+        from the same keys, token for token."""
+        import jax
+        import jax.numpy as jnp
+
+        key, batch, vocab = jax.random.PRNGKey(3), 3, 101
+        k1, k2 = jax.random.split(key)
+        toks = [jax.random.randint(k1, (batch, 1), 0, vocab)]
+        keys = jax.random.split(k2, seq)
+        for i in range(seq - 1):
+            tok = toks[-1]
+            noise = jax.random.bernoulli(keys[i], 0.1, tok.shape)
+            rand = jax.random.randint(keys[i], tok.shape, 0, vocab)
+            toks.append(jnp.where(noise, rand, (tok * 31 + 17) % vocab))
+        want = jnp.concatenate(toks, axis=1)
+        got = synth_token_batch(key, batch, seq, vocab)
+        np.testing.assert_array_equal(got["tokens"], want)
+        np.testing.assert_array_equal(got["targets"],
+                                      jnp.concatenate([want[:, 1:], want[:, :1]], axis=1))
+
+    def test_topic_tokens_split_by_topic_with_a_balanced_root(self):
+        from repro.data.pipeline import build_federated_data
+
+        data = build_federated_data("topics", 6, 0.1, malicious_fraction=0.5, seed=2,
+                                    seq_len=12, vocab=97, root_samples=16)
+        assert data.x.shape[1:] == data.y.shape[1:] == (12,)
+        np.testing.assert_array_equal(data.x[:, 1:], data.y[:, :-1])
+        assert int(data.x.max()) < 97 and data.malicious.sum() == 3
+        rng = np.random.RandomState(0)
+        batch = data.sample_round(rng, np.array([0, 3]), 2, 1)
+        assert batch["x"].shape == batch["y"].shape == (2, 2, 1, 12)
+        root = data.root_batches(rng, 2, 4, 16)
+        assert root["x"].shape == (2, 4, 12)
+        assert set(np.concatenate(data.parts)).isdisjoint(data.root_pool)
+
 
 class TestDirichlet:
     def test_smaller_beta_more_skew(self):

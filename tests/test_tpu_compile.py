@@ -170,3 +170,40 @@ def test_hierarchical_flush_four_pods_one_all_reduce(topo):
         jax.ShapeDtypeStruct((4 * kp,), jnp.float32, sharding=rep),
     )
     assert len(re.findall(r"\ball-reduce(?:-start)?\(", text)) == 1
+
+
+def _compile_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_moonlight_held_experts_grouped_products(one_chip):
+    """The dropless held-expert layer at Moonlight's widths (8 of 64
+    experts of width 1,408 on d = 2,048, 6 per token), forward and input
+    gradient over 512 tokens: grouped-product kernels."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models import moe
+
+    cfg = get_arch("moonlight-16b-a3b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, experts_held=8))
+    params = jax.eval_shape(lambda k: moe.init_moe(k, cfg, jnp.float32), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                          params)
+    x = jax.ShapeDtypeStruct((1, 512, cfg.d_model), jnp.float32, sharding=one_chip)
+    txt = _compile_text(jax.grad(lambda p, x: jnp.sum(moe.moe_mlp(p, cfg, x)[0]), argnums=1),
+                        params, x)
+    assert "ragged-dot" in txt and "tpu_custom_call" in txt
+
+
+def test_moonlight_mla_flash_attention(one_chip):
+    """MLA's causal attention (q/k head 192, v head 128, 16 heads, 4,096
+    positions) through the Pallas flash-attention kernel, forward and
+    backward."""
+    from repro.models import layers
+
+    q = jax.ShapeDtypeStruct((1, 4096, 16, 192), jnp.float32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.float32, sharding=one_chip)
+    txt = _compile_text(jax.grad(lambda q, k, v: jnp.sum(layers._tpu_flash_causal(q, k, v)),
+                                 argnums=(0, 1, 2)), q, q, v)
+    assert txt.count("tpu_custom_call") >= 3
