@@ -8,8 +8,9 @@ id, and :func:`build` returns what an engine needs of it.
   (``models/lora.py``): ``init(key, data)`` returns ``(base, adapters)``
   and ``loss(adapters, batch, base)`` returns ``(loss, counters)``: the
   next-token cross-entropy over the batch's ``x`` tokens and ``y``
-  targets, and in-jit counters (``tokens_trained`` and the MoE layers'
-  ``expert_assignments`` [layers, held experts]).
+  targets, and in-jit counters (``tokens_trained``, the MoE layers'
+  ``expert_assignments`` [layers, held experts] and ``expert_rows``
+  [layers], the row capacity each layer's held dispatch ran).
   The base is an argument, never a constant captured by the jitted round.
 """
 from __future__ import annotations
@@ -106,6 +107,7 @@ def _arch(cfg, adapters) -> Model:
         counters = {
             "tokens_trained": jnp.int32(batch["y"].size),
             "expert_assignments": moe.get("assignments", jnp.zeros((0, 0), jnp.int32)),
+            "expert_rows": moe.get("expert_rows", jnp.zeros((0,), jnp.int32)),
         }
         return T.cross_entropy(logits, batch["y"]), counters
 

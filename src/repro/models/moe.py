@@ -4,8 +4,9 @@ Moonlight).
 A layer told which experts it holds (``MoEConfig.experts_held`` > 0)
 routes over all of them (:func:`route`, DeepSeek-V3's sigmoid scores and
 selection-only bias) and computes its own experts' share of the output,
-dropless: ``_dispatch_held``.  Otherwise, two capacity dispatch
-strategies, selectable via ``MoEConfig.dispatch``:
+dropless, in a row buffer sized on the device from :func:`row_ladder`:
+``_dispatch_held``.  Otherwise, two capacity dispatch strategies,
+selectable via ``MoEConfig.dispatch``:
 
   * ``einsum`` — classic capacity-based one-hot dispatch/combine einsums
     (Switch/GShard style).  Tokens are partitioned into *groups* so the
@@ -21,12 +22,17 @@ Aux load-balance loss: E * sum_e f_e * p_e  (Switch eq. 4).
 """
 from __future__ import annotations
 
+import math
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
 from repro.models.layers import dense_init, linear
 
 GROUP_SIZE = 1024  # tokens per dispatch group (einsum mode)
+ROW_TILE = 128  # the held dispatch's row capacities are multiples of this
+MAX_RUNGS = 4  # row capacities a held layer compiles
 
 
 def init_moe(key, cfg, dtype):
@@ -94,37 +100,114 @@ def route(params, cfg, x):
     return topk_idx, topk_w * e.routed_scaling
 
 
+def row_ladder(t: int, top_k: int, held: int, n_experts: int) -> tuple[int, ...]:
+    """The row capacities the held dispatch chooses from, smallest first.
+
+    The rungs start at 4/3 of the expected count of held assignments,
+    T·k·held/E, and double, each rounded up to ``ROW_TILE``; the last is
+    always T·min(k, held), the most a sequence can route to the held
+    experts (a token picks k distinct experts), so no rung can drop a
+    row.  At most ``MAX_RUNGS``.  With every expert held the expected
+    count is that most, and the ladder is the one rung T·k."""
+    top = t * min(top_k, held)
+    rungs = []
+    c = 4 * t * top_k * held / (3 * n_experts)
+    while len(rungs) < MAX_RUNGS - 1:
+        rung = -(-math.ceil(c) // ROW_TILE) * ROW_TILE
+        if rung >= top:
+            break
+        rungs.append(rung)
+        c *= 2
+    return (*rungs, top)
+
+
+def _held_rows(weights, x, order, sizes, w, rows: int, top_k: int):
+    """The held experts' weighted share of the output from the first
+    ``rows`` rows of the sorted (token, choice) assignments ``order``,
+    those of the held experts first; ``w`` is each assignment's routing
+    weight, zero off the held experts."""
+    idx = order[:rows]
+    tok = idx // top_k
+    # rows past the held groups are left unwritten by the TPU's grouped
+    # product, in its output and in its input gradient: mask both ends
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    w_gate, w_up, w_down = weights
+    xs = jnp.where(live, x[tok], 0.0)
+    g = jax.lax.ragged_dot(xs, w_gate, sizes)
+    u = jax.lax.ragged_dot(xs, w_up, sizes)
+    out = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down, sizes)
+    contrib = jnp.where(live, out * w[idx].astype(x.dtype)[:, None], 0.0)
+    return jnp.zeros_like(x).at[tok].add(contrib)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_ladder(ladder, top_k, rung, weights, x, order, sizes, w):
+    """:func:`_held_rows` on the rung ``rung`` of ``ladder``.  Its
+    gradient saves only the inputs, whose shapes no rung changes, and
+    recomputes the rung's rows in the backward pass: a plain switch
+    under differentiation would save every rung's buffers, the larger
+    ones as zeros."""
+    return jax.lax.switch(rung, [partial(_held_rows, rows=c, top_k=top_k) for c in ladder],
+                          weights, x, order, sizes, w)
+
+
+def _held_ladder_fwd(ladder, top_k, rung, weights, x, order, sizes, w):
+    y = _held_ladder(ladder, top_k, rung, weights, x, order, sizes, w)
+    return y, (rung, weights, x, order, sizes, w)
+
+
+def _held_ladder_bwd(ladder, top_k, res, ct):
+    rung, weights, x, order, sizes, w = res
+
+    def rung_vjp(rows):
+        def vjp(weights, x, w, order, sizes):
+            f = lambda weights, x, w: _held_rows(weights, x, order, sizes, w, rows, top_k)
+            return jax.vjp(f, weights, x, w)[1](ct)
+        return vjp
+
+    d_weights, dx, dw = jax.lax.switch(rung, [rung_vjp(c) for c in ladder],
+                                       weights, x, w, order, sizes)
+    return None, d_weights, dx, None, None, dw
+
+
+_held_ladder.defvjp(_held_ladder_fwd, _held_ladder_bwd)
+
+
 def _dispatch_held(params, cfg, x):
     """Dropless dispatch onto the experts this device holds.  x: [T, d].
 
     Every (token, choice) assignment is sorted by its expert, those of
     the held experts first and in expert order, and each held expert's
     rows go through its SwiGLU as one group of a grouped product
-    (``lax.ragged_dot``), however many there are: the group sizes are
-    the count of held assignments and the row buffer holds every (token,
-    choice) row, so nothing can be dropped.  Returns the held experts'
-    weighted share of the output and the assignments computed per held
-    expert."""
+    (``lax.ragged_dot``), however many there are.  The row buffer has
+    the smallest capacity of :func:`row_ladder` that holds the count of
+    held assignments, picked on the device (``lax.switch``); its top
+    rung holds every row a sequence can route to the held experts, so
+    nothing can be dropped.  Each rung's rows are recomputed under
+    differentiation (:func:`_held_ladder`), so that the switch saves only
+    its inputs, which have one shape on every rung.  With every expert
+    held the ladder is one rung of T·k rows and there is no switch.
+    Returns the held experts' weighted share of the
+    output and the counters ``assignments`` (per held expert) and
+    ``expert_rows`` (the capacity the layer ran)."""
     e = cfg.moe
-    t, d = x.shape
     held = e.experts_held
     topk_idx, topk_w = route(params, cfg, x)
     local = topk_idx.reshape(-1) - e.expert_offset
     mine = (local >= 0) & (local < held)
     group = jnp.where(mine, local, held)
     order = jnp.argsort(group, stable=True)
-    tok = order // e.top_k
     sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
-    # rows past the held groups are left unwritten by the TPU's grouped
-    # product, in its output and in its input gradient: mask both ends
-    rows = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
-    xs = jnp.where(rows, x[tok], 0.0)
-    g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
-    u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
-    out = jax.lax.ragged_dot(jax.nn.silu(g) * u, params["w_down"], sizes)
-    w = jnp.where(mine, topk_w.reshape(-1), 0.0)[order].astype(x.dtype)
-    y = jnp.zeros((t, d), x.dtype).at[tok].add(jnp.where(rows, out * w[:, None], 0.0))
-    return y, {"assignments": sizes}
+    w = jnp.where(mine, topk_w.reshape(-1), 0.0)
+    args = ((params["w_gate"], params["w_up"], params["w_down"]), x, order, sizes, w)
+    ladder = row_ladder(x.shape[0], e.top_k, held, e.n_experts)
+    if len(ladder) == 1:
+        y = _held_rows(*args, rows=ladder[0], top_k=e.top_k)
+        rung = 0
+    else:
+        rung = jnp.searchsorted(jnp.asarray(ladder), jnp.sum(sizes))
+        y = _held_ladder(ladder, e.top_k, rung, *args)
+    return y, {"assignments": sizes, "expert_rows": jnp.asarray(ladder, jnp.int32)[rung]}
 
 
 def _experts_ffn(params, h_in):
@@ -208,8 +291,8 @@ def _dispatch_sort(params, cfg, x, shard):
 
 def moe_mlp(params, cfg, x, shard=lambda t, n: t):
     """x: [B, S, d] -> ([B, S, d], aux_loss, counters).  ``counters``
-    (assignments per held expert) come from the held dispatch; the
-    capacity dispatches return none."""
+    (assignments per held expert and the row capacity run) come from the
+    held dispatch; the capacity dispatches return none."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     counts = {}

@@ -304,8 +304,9 @@ def forward_counted(
 ):
     """:func:`forward` plus the MoE layers' counters: (logits, new_cache,
     aux_loss, counts), ``counts`` holding per scanned stack and slot the
-    held dispatch's ``assignments`` [n_blocks, experts_held] (empty for
-    the capacity dispatches)."""
+    held dispatch's ``assignments`` [n_blocks, experts_held] and
+    ``expert_rows`` [n_blocks], the row capacity each block ran (empty
+    for the capacity dispatches)."""
     pattern, tail = pattern_of(cfg)
 
     if cfg.arch_type == "audio":

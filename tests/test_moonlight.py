@@ -109,6 +109,57 @@ def test_no_token_dropped_when_routing_piles_onto_one_expert(cfg, base):
     np.testing.assert_allclose(y[0], want, rtol=2e-5, atol=2e-5)
 
 
+def _held_share(cfg, p, held, offset):
+    share_cfg = dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, experts_held=held, expert_offset=offset))
+    share = dict(p, **{k: p[k][offset: offset + held] for k in ("w_gate", "w_up", "w_down")})
+    return share_cfg, share
+
+
+@pytest.mark.parametrize("held, bias, rows", [
+    (2, 0.0, 384),  # the router alone: ~T·k/4 held assignments, the lowest rung
+    (2, 0.2, 768),  # a bias that piles most tokens onto the held experts: a middle rung
+    (2, 100.0, 1024),  # every token on both held experts: the top rung, T·k
+    (4, 0.0, 768),
+    (4, 100.0, 1024),
+])
+def test_held_dispatch_is_exact_on_every_rung(cfg, base, held, bias, rows):
+    """The held dispatch in a row buffer of the capacity the routing
+    needs: the output and its gradient with respect to x are the dense
+    reference's, and every held assignment is counted."""
+    offset = held
+    share_cfg, share = _held_share(cfg, _moe_params(base), held, offset)
+    share["router_bias"] = jnp.zeros_like(share["router_bias"]).at[offset: offset + held].set(bias)
+    x = _x(cfg, n=512, seed=11)
+    ct = jax.random.normal(jax.random.PRNGKey(12), x.shape)
+    rc = ref_cfg(cfg, expert_offset=offset)
+    (y, counts), vjp = jax.vjp(lambda x: MOE._dispatch_held(share, share_cfg, x), x)
+    with jax.default_matmul_precision("highest"):
+        want, want_vjp = jax.vjp(lambda x: ref.held_experts(share, x, rc), x)
+        routed = ref.route(share, x, rc)[:, offset: offset + held]
+        np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(vjp((ct, jax.tree.map(jnp.zeros_like, counts)))[0],
+                                   want_vjp(ct)[0], rtol=2e-5, atol=2e-5)
+    assert MOE.row_ladder(x.shape[0], cfg.moe.top_k, held, cfg.moe.n_experts)[-1] == 1024
+    assert int(counts["expert_rows"]) == rows
+    assert int(jnp.sum(counts["assignments"])) == int(jnp.sum(routed > 0))
+
+
+def test_every_expert_held_runs_one_rung_without_a_switch(cfg, base):
+    """smoke()'s layer holds all its experts: one rung of T·k rows, no
+    switch in the program; holding fewer brings the switch in."""
+    p = _moe_params(base)
+    x = _x(cfg, n=512)[None]
+    assert cfg.moe.experts_held == cfg.moe.n_experts
+    assert MOE.row_ladder(512, cfg.moe.top_k, cfg.moe.n_experts, cfg.moe.n_experts) == (1024,)
+    whole = str(jax.make_jaxpr(lambda x: MOE.moe_mlp(p, cfg, x))(x))
+    assert "ragged_dot" in whole and "cond[" not in whole
+    share_cfg, share = _held_share(cfg, p, 2, 0)
+    assert "cond[" in str(jax.make_jaxpr(lambda x: MOE.moe_mlp(share, share_cfg, x))(x))
+    _, _, counts = MOE.moe_mlp(p, cfg, x)
+    assert int(counts["expert_rows"]) == 512 * cfg.moe.top_k
+
+
 def test_fresh_adapters_leave_the_base_as_it_is(cfg, base):
     ad = lora.init_adapters(jax.random.PRNGKey(5), base, lora.TARGETS, RANK)
     assert all(float(jnp.abs(b).max()) == 0.0 for b in jax.tree.leaves(
@@ -178,6 +229,8 @@ def test_brdrag_alie_round_matches_reference_round(cfg):
     s, u = run.spec.regime.n_selected, run.spec.regime.local_steps
     assert int(metrics["tokens_trained"]) == (s + 1) * u * 16
     assert int(metrics["expert_assignments"].sum()) == (s + 1) * u * 16 * cfg.moe.top_k
+    # every expert held: each training's layer ran the one rung of T·k rows
+    np.testing.assert_array_equal(metrics["expert_rows"], [(s + 1) * u * 16 * cfg.moe.top_k])
 
 
 def test_runs_through_compile_run():

@@ -207,3 +207,32 @@ def test_moonlight_mla_flash_attention(one_chip):
     txt = _compile_text(jax.grad(lambda q, k, v: jnp.sum(layers._tpu_flash_causal(q, k, v)),
                                  argnums=(0, 1, 2)), q, q, v)
     assert txt.count("tpu_custom_call") >= 3
+
+
+def test_held_experts_row_ladder_keeps_its_buffers_small(one_chip):
+    """The input gradient of one held MoE layer (smoke widths, 4,096
+    tokens, 2 experts a token) holding 1 of 8 experts, whose row ladder
+    has three rungs, needs at most half the temporaries of the layer that
+    holds all 8 at the same T and k: the switch between rungs saves only
+    its inputs, and none of the larger rungs' buffers."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models import moe
+
+    def temp_bytes(held):
+        cfg = get_arch("moonlight-16b-a3b", smoke=True)
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, experts_held=held))
+        assert len(moe.row_ladder(4096, cfg.moe.top_k, held, cfg.moe.n_experts)) == (
+            3 if held == 1 else 1)
+        params = jax.eval_shape(lambda k: moe.init_moe(k, cfg, jnp.float32),
+                                jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                             sharding=one_chip), params)
+        x = jax.ShapeDtypeStruct((1, 4096, cfg.d_model), jnp.float32, sharding=one_chip)
+        grad = jax.grad(lambda x, p: jnp.sum(moe.moe_mlp(p, cfg, x)[0] ** 2))
+        compiled = jax.jit(grad).lower(x, params).compile()
+        assert "ragged-dot" in compiled.as_text()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    assert temp_bytes(1) <= temp_bytes(8) / 2
