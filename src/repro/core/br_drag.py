@@ -141,12 +141,16 @@ def round_step_flat(
     discounts=None,
     weights=None,
     interpret=None,
-) -> tuple[pt.Pytree, dict, tuple]:
-    """:func:`round_step` on the flat plane given the flat trusted r^t.
+    reduce=kops.calibrated_reduce,
+) -> tuple[pt.Pytree, dict, tuple, jax.Array]:
+    """:func:`round_step` on the flat plane given the flat trusted r^t;
+    ``reduce`` as in ``drag.round_step_flat``.
 
     Returns (params', metrics, (dots, g_sq, r_sq), lam [S])."""
-    delta_flat, lams, stats = aggregate_flat(
-        stack.data, reference_flat, c, discounts, weights, interpret=interpret
+    w = kops.normalize_weights(weights, stack.data.shape[0])
+    delta_flat, lams, stats = reduce(
+        stack.data, reference_flat, c, "br_drag", w=w, discounts=discounts,
+        interpret=interpret,
     )
     new_params = pt.tree_add(params, flat_mod.unflatten_tree(delta_flat, stack.spec))
     metrics = {

@@ -184,6 +184,7 @@ def round_step_flat(
     discounts=None,
     weights=None,
     interpret=None,
+    reduce=kops.calibrated_reduce,
 ) -> tuple[pt.Pytree, "DragState", dict, tuple]:
     """:func:`round_step` on the flat plane — the serving path.
 
@@ -194,7 +195,9 @@ def round_step_flat(
     (``kops.flush_path``): the bootstrap switch is a select on the
     [S]-sized blend coefficients, never a separate raw-mean pass, and the
     reference round-trips through its flat form so only [d]-sized
-    vectors are unflattened.
+    vectors are unflattened.  ``reduce`` is the plane's calibrated
+    reduction (``kops.calibrated_reduce``'s signature; the sharded plane
+    passes its per-pod one, ``repro.stream.sharded.pod_reduce``).
 
     Returns (params', state', metrics, (dots, g_sq, r_sq)) — the stats
     are against the PRE-update reference, exactly what the trust layer
@@ -206,7 +209,7 @@ def round_step_flat(
     w = kops.normalize_weights(weights, s)
     init = state.initialized
     # bootstrap (eq. 5a): uniform raw mean — a = 1, b = 0, w = 1/S
-    delta_flat, lam, (dots, gsq, rsq) = kops.calibrated_reduce(
+    delta_flat, lam, (dots, gsq, rsq) = reduce(
         g, r_flat, c, "drag", w=w, discounts=discounts, init=init,
         boot_aw=jnp.full((s,), 1.0 / s, jnp.float32), interpret=interpret,
     )

@@ -1,14 +1,16 @@
 """The jitted federated round — simulation regime.
 
 One call = one full paper round: S parallel local-SGD clients ->
-flatten onto the [S, d] update plane (``repro.core.flat``) -> optional
-Byzantine update attack (flat rows) -> server aggregation (flat-tier
-rules / fused two-pass DRAG kernels) -> one unflatten of the [d] delta
--> global model + server-state update.
+flatten onto the [S, d] update plane (``repro.core.flat``) -> for
+BR-DRAG / FLTrust the root's reference pass -> the server's
+aggregation step (``repro.fl.aggregate.server_step``: attack, trust,
+the rule through the flat kernels, telemetry, monitor; the flushes of
+``repro.stream`` run the same step) -> the FedACG / SCAFFOLD server
+state.
 
-The production-regime round (clients = mesh axis groups, collectives
-instead of vmap) lives in ``repro.launch.train``; both share the same
-core math from ``repro.core``.
+``repro.launch.train.make_fl_round_step`` is a further round over mesh
+axis groups (collectives instead of vmap); only the dry-run grid
+(``repro.launch.dryrun``) runs it, no served path.
 """
 from __future__ import annotations
 
@@ -19,12 +21,11 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.adversary import engine as adversary_engine
-from repro.core import aggregators, br_drag, drag
+from repro.core import drag
 from repro.core import flat as flat_mod
 from repro.core import pytree as pt
+from repro.fl.aggregate import init_step_states, server_step
 from repro.fl.client import local_update, with_counters
-from repro.trust import reputation as trust_mod
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,21 +77,7 @@ def init_server_state(
     # Copy params: the jitted round fn donates the state, and donating a
     # buffer the caller still aliases (e.g. two states built from the same
     # init) would invalidate it out from under them.
-    #
-    # ``cfg`` sizes the adversary memory and the trust table; without it
-    # both stay empty — fine for stateless attacks with trust off (the
-    # pre-engine behaviour), enforced in ``federated_round``.
-    adv_state: pt.Pytree = ()
-    trust_state: pt.Pytree = ()
-    monitor_state: pt.Pytree = ()
-    if cfg is not None:
-        adv_state = adversary_engine.resolve(cfg.attack, dict(cfg.attack_kw)).init()
-        if cfg.trust:
-            trust_state = trust_mod.init_trust(n_workers)
-        if cfg.telemetry and cfg.monitor is not None:
-            from repro.obs import monitor as obs_monitor
-
-            monitor_state = obs_monitor.monitor_init()
+    adv_state, trust_state, monitor_state = init_step_states(cfg, n_workers)
     return ServerState(
         params=jax.tree.map(lambda x: jnp.array(x, copy=True), params),
         round=jnp.zeros((), jnp.int32),
@@ -143,7 +130,7 @@ def _client_updates(loss_fn, state: ServerState, cfg: RoundConfig, batches, sele
     # params diverge during local SGD) which XLA:CPU executes ~17x
     # slower, and while-loops (lax.map/scan) are ~11x slower than
     # straight-line code on the CPU backend.  S is small and static in
-    # the paper's protocol.  The production regime parallelises clients
+    # the paper's protocol.  The dry-run's round parallelises clients
     # over mesh axes instead (repro.launch.train).
     s = jax.tree.leaves(batches)[0].shape[0]
     outs = [one((pt.tree_index(batches, i), selected_idx[i])) for i in range(s)]
@@ -163,7 +150,6 @@ def federated_round(
     root_batches=None,  # [U, B, ...] — BR-DRAG / FLTrust root data
     frozen=None,  # the frozen base of an architecture's adapters
 ) -> tuple[ServerState, dict]:
-    s = malicious_mask.shape[0]
     if cfg.model_kind == "arch":
         loss = partial(loss_fn, base=frozen)  # (loss, counters)
     else:
@@ -177,162 +163,58 @@ def federated_round(
     # crafting, calibration, trust signals, and reduction; only the
     # aggregated [d] delta is unflattened, once, onto the params
     stack = flat_mod.stack_updates(g_stacked, client_ids=selected_idx)
-    spec = stack.spec
 
-    # ---- Byzantine update-space attack: the adversary engine sees the
-    # honest stack (omniscient threat model) and threads its memory
-    # through the server state
-    adv = adversary_engine.resolve(cfg.attack, dict(cfg.attack_kw))
-    if jax.tree.structure(state.adversary) != jax.tree.structure(adv.init()):
-        raise ValueError(
-            f"attack {cfg.attack!r} carries state; build the server state "
-            "with init_server_state(params, n_workers, cfg)"
-        )
-    ctx = adversary_engine.AttackContext(
-        key=key, updates=stack.data, malicious_mask=malicious_mask,
-        round=state.round, spec=spec,
-    )
-    g_flat, new_adv = adv.craft(state.adversary, ctx)
-    stack = dataclasses.replace(stack, data=g_flat)
-
-    # ---- trust layer: reputation weights from PAST rounds' divergence
-    # history weight this round's aggregation; this round's divergences
-    # are folded into the history afterwards
-    use_trust = cfg.trust and cfg.algorithm in ("drag", "br_drag")
-    if cfg.trust and not use_trust:
-        raise ValueError(
-            f"trust reputation needs a reference direction; algorithm "
-            f"{cfg.algorithm!r} has none (use drag or br_drag)"
-        )
-    if use_trust and not isinstance(state.trust, trust_mod.TrustState):
-        raise ValueError(
-            "cfg.trust=True needs a trust table; build the server state "
-            "with init_server_state(params, n_workers, cfg)"
-        )
-    tcfg = trust_mod.TrustConfig(**dict(cfg.trust_kw)) if use_trust else None
-    weights = (
-        # stack.client_ids IS selected_idx — the stack metadata is the
-        # single source the trust layer indexes by
-        trust_mod.reputation(state.trust, stack.client_ids, tcfg) if use_trust else None
-    )
-
-    metrics: dict = {}
-    new_drag = state.drag
-    new_momentum = state.momentum
-    new_h = state.control_global
-    new_hm = state.control_workers
-    new_trust = state.trust
-    params = state.params
-    update_norms = None  # [S] row norms; free from the kernel stats below
-    stats_obs = None  # phase-1 scalars for the telemetry bundle, when any
-
-    if cfg.algorithm == "drag":
-        params, new_drag, dm, stats = drag.round_step_flat(
-            params, state.drag, stack, alpha=cfg.alpha, c=cfg.c,
-            weights=weights,
-        )
-        metrics.update(dm)
-        update_norms = jnp.sqrt(stats[1])
-        stats_obs = stats
-        if use_trust:
-            div, nr = trust_mod.signals_from_stats(*stats)
-            # no reference on the bootstrap round -> no observation
-            new_trust = trust_mod.observe(
-                state.trust, stack.client_ids, div, nr, tcfg, gate=state.drag.initialized
-            )
-    elif cfg.algorithm in ("br_drag", "fltrust"):
+    r_flat = None
+    if cfg.algorithm in ("br_drag", "fltrust"):
         assert root_batches is not None, f"{cfg.algorithm} needs a root dataset"
         # the root trains as a client does: r^t = theta^{t,U} - theta^t
-        reference, root_aux = local_update(loss, params, root_batches, cfg.lr,
+        reference, root_aux = local_update(loss, state.params, root_batches, cfg.lr,
                                            unroll=cfg.model_kind == "cnn")
         counters = jax.tree.map(jnp.add, counters, root_aux["counters"])
         r_flat = flat_mod.flatten_tree(reference)
-        if cfg.algorithm == "br_drag":
-            params, dm, stats, lams = br_drag.round_step_flat(
-                params, stack, r_flat, c=cfg.c_br, weights=weights
-            )
-            metrics.update(dm, dod=lams)
-            update_norms = jnp.sqrt(stats[1])
-            stats_obs = stats
-            if use_trust:
-                div, nr = trust_mod.signals_from_stats(*stats)
-                new_trust = trust_mod.observe(state.trust, stack.client_ids, div, nr, tcfg)
-        else:
-            delta_flat = aggregators.fltrust_flat(stack.data, r_flat)
-            params = pt.tree_add(params, flat_mod.unflatten_tree(delta_flat, spec))
-            metrics["delta_norm"] = jnp.linalg.norm(delta_flat)
-    else:
-        # registry-driven dispatch: every non-reference rule is reachable
-        # by name through the FLAT tier; the client-side variants
-        # (fedprox/scaffold/fedacg) reduce with the plain mean.
-        rule = "fedavg" if cfg.algorithm in aggregators.MEAN_REDUCED else cfg.algorithm
-        if rule not in aggregators.FLAT_CAPABLE or rule in aggregators.NEEDS_REFERENCE:
-            raise ValueError(f"unknown algorithm {cfg.algorithm}")
-        delta_flat = aggregators.FLAT_AGGREGATORS[rule](
-            stack.data,
-            **aggregators.rule_kwargs(
-                rule, n_byzantine=cfg.n_byzantine_hint, geomed_iters=cfg.geomed_iters
-            ),
-        )
-        delta = flat_mod.unflatten_tree(delta_flat, spec)
-        params = pt.tree_add(params, delta)
-        metrics["delta_norm"] = jnp.linalg.norm(delta_flat)
-        if cfg.algorithm == "fedacg":
-            new_momentum = pt.tree_axpy(cfg.acg_lambda, state.momentum, delta)
-        if cfg.algorithm == "scaffold":
-            n_workers = jax.tree.leaves(state.control_workers)[0].shape[0]
-            new_controls = aux["new_control"]  # [S, ...]
-            old_controls = jax.vmap(lambda i: pt.tree_index(state.control_workers, i))(
-                selected_idx
-            )
-            # h <- h + (1/M) sum_S (new - old)
-            diff = jax.tree.map(lambda a, b: jnp.sum(a - b, 0) / n_workers, new_controls, old_controls)
-            new_h = pt.tree_add(state.control_global, diff)
-            new_hm = jax.tree.map(
-                lambda all_h, upd: all_h.at[selected_idx].set(upd),
-                state.control_workers,
-                new_controls,
-            )
 
-    if use_trust:
-        metrics["trust_weight_mean"] = jnp.mean(weights)
-        metrics["quarantined"] = jnp.sum(new_trust.quarantined.astype(jnp.int32))
-    if update_norms is None:
-        update_norms = jnp.linalg.norm(stack.data, axis=1)
-    metrics["update_norm_mean"] = jnp.mean(update_norms)
+    step = server_step(
+        cfg, state.params, state.drag, state.adversary, state.trust, state.monitor,
+        stack, malicious_mask, state.round, key, reference=r_flat,
+    )
+    metrics = step.metrics
+    if cfg.algorithm == "br_drag":
+        metrics["dod"] = step.lam  # the per-client DoD the round reports
+    new_momentum = state.momentum
+    new_h = state.control_global
+    new_hm = state.control_workers
+    if cfg.algorithm == "fedacg":
+        delta = flat_mod.unflatten_tree(step.delta, stack.spec)
+        new_momentum = pt.tree_axpy(cfg.acg_lambda, state.momentum, delta)
+    if cfg.algorithm == "scaffold":
+        n_workers = jax.tree.leaves(state.control_workers)[0].shape[0]
+        new_controls = aux["new_control"]  # [S, ...]
+        old_controls = jax.vmap(lambda i: pt.tree_index(state.control_workers, i))(
+            selected_idx
+        )
+        # h <- h + (1/M) sum_S (new - old)
+        diff = jax.tree.map(lambda a, b: jnp.sum(a - b, 0) / n_workers, new_controls, old_controls)
+        new_h = pt.tree_add(state.control_global, diff)
+        new_hm = jax.tree.map(
+            lambda all_h, upd: all_h.at[selected_idx].set(upd),
+            state.control_workers,
+            new_controls,
+        )
     # in-jit counters of every client's and the root's training steps
     metrics.update(counters)
-    if cfg.telemetry:
-        from repro.obs import metrics as obs_metrics
-
-        # the sync regime has no staleness and no ingest buffer: taus /
-        # discounts / drops stay at their defaults, fill = capacity = S
-        metrics["obs"] = obs_metrics.flush_bundle(
-            rnd=state.round, fill=s, capacity=s,
-            stats=stats_obs, update_norms=update_norms, reputations=weights,
-            trust_state=new_trust if use_trust else None,
-            c=cfg.c if cfg.algorithm == "drag" else cfg.c_br,
-            mode=cfg.algorithm if cfg.algorithm in ("drag", "br_drag") else "none",
-        )
     new_monitor = state.monitor
-    if cfg.telemetry and cfg.monitor is not None:
-        from repro.obs import monitor as obs_monitor
-
-        mstate = state.monitor if state.monitor != () else obs_monitor.monitor_init()
-        new_monitor, verdict = obs_monitor.monitor_step(
-            mstate, metrics["obs"], cfg.monitor
-        )
+    if step.monitor is not None:
         # the verdict is telemetry: the host loop pops it for the session
-        metrics["obs_alerts"] = verdict
+        new_monitor, metrics["obs_alerts"] = step.monitor
     new_state = ServerState(
-        params=params,
+        params=step.params,
         round=state.round + 1,
-        drag=new_drag,
+        drag=step.drag,
         momentum=new_momentum,
         control_global=new_h,
         control_workers=new_hm,
-        adversary=new_adv,
-        trust=new_trust,
+        adversary=step.adversary,
+        trust=step.trust,
         monitor=new_monitor,
     )
     return new_state, metrics

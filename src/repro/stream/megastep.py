@@ -20,10 +20,12 @@ compiles the loop itself:
     ``(params, buffer, trust, monitor, metrics-ring, ...)``, and thousands
     of events complete per host round-trip.
 
-The flush itself is the UNCHANGED ``repro.stream.server.flush`` — the
-megastep only removes the host from between events, so every robustness
-property (adversary engine, staleness discounts, trust weighting,
-sharded emulation) is inherited, and :func:`serve_unrolled` — the same
+The flush itself is the host server's ``repro.stream.server.flush``,
+looked up at trace time, and through it the one server step every
+engine shares (``repro.fl.aggregate.server_step``) — the megastep only
+removes the host from between events, so every robustness property
+(adversary engine, staleness discounts, trust weighting, sharded
+emulation) is inherited, and :func:`serve_unrolled` — the same
 hash regime driven per-event through the host ``AsyncStreamServer``
 methods — pins the compiled path bit-for-bit at ``block=1``.
 

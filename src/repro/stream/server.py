@@ -4,8 +4,9 @@ The serving loop is::
 
     completion event -> client update (vs. the params SNAPSHOT the client
     was dispatched with) -> donated buffer ingest -> threshold flush
-    (any rule in ``aggregators.AGGREGATORS``, staleness-aware for
-    DRAG/BR-DRAG) -> global step -> reference EMA update -> re-dispatch
+    (the server step every engine shares, ``repro.fl.aggregate``: any
+    rule of the flat registry, staleness-aware for DRAG/BR-DRAG) ->
+    global step -> reference EMA update -> re-dispatch
 
 Clients never block each other: an upload lands in the fixed-capacity
 ingest buffer (``repro.stream.buffer``) tagged with the model version it
@@ -49,16 +50,15 @@ from repro.adversary import engine as adversary_engine
 from repro.core import aggregators, br_drag, drag
 from repro.core import flat as flat_mod
 from repro.core import pytree as pt
+from repro.fl import aggregate as fl_aggregate
 from repro.fl.client import local_update, with_counters
-from repro.obs import metrics as obs_metrics
-from repro.obs import monitor as obs_monitor
+from repro.kernels import ops as kops
 from repro.obs import session as obs_session
 from repro.obs import trace as obs_trace
 from repro.stream import buffer as buf_mod
 from repro.stream import sharded as sharded_mod
 from repro.stream import staleness as stale
 from repro.stream.events import EventStream
-from repro.trust import reputation as trust_mod
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,22 +114,10 @@ def init_stream_state(
 ) -> StreamState:
     # Copy params for the same aliasing reason as fl.round.init_server_state.
     #
-    # ``cfg`` sizes the adversary memory and (with ``n_clients``) the
-    # trust table; without it both stay empty — the pre-engine behaviour.
     # ``cfg.shards > 0`` swaps the flat [K, d] buffer for p pod-sharded
     # [K/p, d] sub-buffers (``repro.stream.sharded``); ``mesh`` places
     # them over its "pod" axis.
-    adv_state: pt.Pytree = ()
-    trust_state: pt.Pytree = ()
-    monitor_state: pt.Pytree = ()
-    if cfg is not None:
-        adv_state = adversary_engine.resolve(cfg.attack, dict(cfg.attack_kw)).init()
-        if cfg.trust:
-            if not n_clients:
-                raise ValueError("cfg.trust=True needs n_clients for the trust table")
-            trust_state = trust_mod.init_trust(n_clients)
-        if cfg.telemetry and cfg.monitor is not None:
-            monitor_state = obs_monitor.monitor_init()
+    adv_state, trust_state, monitor_state = fl_aggregate.init_step_states(cfg, n_clients)
     if cfg is not None and cfg.shards > 0:
         buffer = sharded_mod.init_sharded_buffer(params, capacity, cfg.shards, mesh)
     else:
@@ -143,6 +131,12 @@ def init_stream_state(
         trust=trust_state,
         monitor=monitor_state,
     )
+
+
+#: stream algorithms with a hierarchical (one-psum) sharded flush —
+#: per-row blend coefficients are pod-local for these, so the cross-pod
+#: traffic is exactly the partial [d] sums
+SHARDABLE = ("fedavg", "drag", "br_drag")
 
 
 def flush(
@@ -164,88 +158,46 @@ def flush(
     (params', drag', round+1, reset buffer, adv_state', trust_state',
     metrics).
 
-    The whole step runs on the flat update plane (``repro.core.flat``):
-    ``buf.slots`` is already the [K, d] stack, the adversary crafts flat
-    rows, DRAG/BR-DRAG dispatch to the fused two-HBM-pass kernels with
-    the staleness discounts and trust weights folded into the reduction
-    epilogue, and the trust signals reuse the calibration's phase-1
-    scalars — only the aggregated [d] delta is ever unflattened.
-
-    A sharded buffer (``cfg.shards > 0``) takes the hierarchical path:
-    per-pod fused passes whose partials meet in one psum.
+    The buffer IS the flat plane: its view as an ``UpdateStack`` (whose
+    staleness tags and client ids feed the discounts and the trust
+    layer) goes through ``repro.fl.aggregate.server_step`` with the
+    staleness discounts phi(tau) and the buffer's fill, capacity and
+    drops.  A sharded buffer (``cfg.shards > 0``) is viewed pod-major
+    and reduces through the per-pod passes whose partials meet in one
+    psum (``repro.stream.sharded.pod_reduce``).  With a monitor its
+    output, (state', verdict), rides back as ``metrics["obs_monitor"]``.
     """
+    if cfg.algorithm in aggregators.MEAN_REDUCED and cfg.algorithm != "fedavg":
+        # unlike fl.round, there is no client-variant objective here —
+        # stream clients run plain SGD, so silently reducing these with
+        # the mean would mislabel fedavg results
+        raise ValueError(
+            f"{cfg.algorithm} needs client-variant local objectives; "
+            "stream clients run plain SGD — use the synchronous regime"
+        )
+    spec = flat_mod.spec_of(params)
     if isinstance(buf, sharded_mod.ShardedBufferState):
-        return _flush_sharded(
-            loss_fn, cfg, params, drag_state, rnd, buf, key,
-            root_batches=root_batches, adv_state=adv_state,
-            trust_state=trust_state, reference=reference, mesh=mesh,
-            monitor_state=monitor_state,
-        )
-    # the buffer IS the flat plane: view it as the UpdateStack whose
-    # metadata (staleness tags, client ids) is THE source the discounts
-    # and the trust layer consume below
-    stack = buf_mod.as_stack(buf, flat_mod.spec_of(params), rnd)
-    spec = stack.spec
-    taus = stack.staleness
-    discounts = stale.make_discount(cfg.discount, cfg.discount_a)(taus)
-
-    # ---- Byzantine update-space attack over the buffered stack: the
-    # adversary sees the staleness tags and discounts it may hide behind
-    adv = adversary_engine.resolve(cfg.attack, dict(cfg.attack_kw))
-    if jax.tree.structure(adv_state) != jax.tree.structure(adv.init()):
-        raise ValueError(
-            f"attack {cfg.attack!r} carries state; build the stream state "
-            "with init_stream_state(params, capacity, cfg)"
-        )
-    ctx = adversary_engine.AttackContext(
-        key=key, updates=stack.data, malicious_mask=buf.malicious, round=rnd,
-        taus=taus, discounts=discounts, spec=spec,
-    )
-    g, new_adv = adv.craft(adv_state, ctx)
-    stack = dataclasses.replace(stack, data=g)
-
-    # ---- trust layer: PAST flushes' divergence history weights this one
-    use_trust = cfg.trust and cfg.algorithm in ("drag", "br_drag")
-    if cfg.trust and not use_trust:
-        raise ValueError(
-            f"trust reputation needs a reference direction; stream algorithm "
-            f"{cfg.algorithm!r} has none (use drag or br_drag)"
-        )
-    if use_trust and not isinstance(trust_state, trust_mod.TrustState):
-        raise ValueError(
-            "cfg.trust=True needs a trust table; build the stream state "
-            "with init_stream_state(params, capacity, cfg, n_clients)"
-        )
-    tcfg = trust_mod.TrustConfig(**dict(cfg.trust_kw)) if use_trust else None
-    weights = (
-        trust_mod.reputation(trust_state, stack.client_ids, tcfg) if use_trust else None
-    )
-
-    metrics: dict = {
-        "staleness_mean": jnp.mean(taus.astype(jnp.float32)),
-        "staleness_max": jnp.max(taus),
-        "discount_mean": jnp.mean(discounts),
-    }
-    new_drag = drag_state
-    new_trust = trust_state
-    update_norms = None  # [K] row norms; free from the kernel stats below
-    stats_obs = None  # phase-1 scalars for the telemetry bundle, when any
-
-    if cfg.algorithm == "drag":
-        params, new_drag, dm, stats = drag.round_step_flat(
-            params, drag_state, stack, alpha=cfg.alpha, c=cfg.c,
-            discounts=discounts, weights=weights,
-        )
-        metrics.update(dm)
-        update_norms = jnp.sqrt(stats[1])
-        stats_obs = stats
-        if use_trust:
-            div, nr = trust_mod.signals_from_stats(*stats)
-            new_trust = trust_mod.observe(
-                trust_state, stack.client_ids, div, nr, tcfg,
-                gate=drag_state.initialized,
+        if cfg.algorithm not in SHARDABLE:
+            raise ValueError(
+                f"stream algorithm {cfg.algorithm!r} has no hierarchical sharded "
+                f"flush (shardable: {SHARDABLE}); use shards=0"
             )
-    elif cfg.algorithm in ("br_drag", "fltrust"):
+        stack = sharded_mod.as_stack(buf, spec, rnd)
+        malicious = buf.malicious.reshape(stack.s)
+        facts = {"fill": sharded_mod.total_count(buf), "pod_fill": buf.counts,
+                 "capacity": sharded_mod.capacity_of(buf), "drops": buf.drops}
+        reduce = sharded_mod.pod_reduce(sharded_mod.n_pods(buf), mesh)
+        reset = sharded_mod.reset
+    else:
+        stack = buf_mod.as_stack(buf, spec, rnd)
+        malicious = buf.malicious
+        facts = {"fill": buf.count, "capacity": buf_mod.capacity_of(buf), "drops": buf.drops}
+        reduce = kops.calibrated_reduce
+        reset = buf_mod.reset
+    discounts = stale.make_discount(cfg.discount, cfg.discount_a)(stack.staleness)
+
+    r_flat = None
+    if cfg.algorithm in ("br_drag", "fltrust"):
         if reference is None:
             assert root_batches is not None, f"{cfg.algorithm} needs a root dataset"
             grad_fn = jax.grad(loss_fn)
@@ -253,213 +205,18 @@ def flush(
                 params, lambda p, b: grad_fn(p, b), root_batches, cfg.lr
             )
         r_flat = flat_mod.flatten_tree(reference)
-        if cfg.algorithm == "br_drag":
-            params, dm, stats, _ = br_drag.round_step_flat(
-                params, stack, r_flat, c=cfg.c_br, discounts=discounts,
-                weights=weights,
-            )
-            metrics.update(dm)
-            update_norms = jnp.sqrt(stats[1])
-            stats_obs = stats
-            if use_trust:
-                div, nr = trust_mod.signals_from_stats(*stats)
-                new_trust = trust_mod.observe(
-                    trust_state, stack.client_ids, div, nr, tcfg
-                )
-        else:
-            delta_flat = aggregators.fltrust_flat(g, r_flat)
-            params = pt.tree_add(params, flat_mod.unflatten_tree(delta_flat, spec))
-            metrics["delta_norm"] = jnp.linalg.norm(delta_flat)
-    else:
-        if cfg.algorithm in aggregators.MEAN_REDUCED and cfg.algorithm != "fedavg":
-            # unlike fl.round, there is no client-variant objective here —
-            # stream clients run plain SGD, so silently reducing these with
-            # the mean would mislabel fedavg results
-            raise ValueError(
-                f"{cfg.algorithm} needs client-variant local objectives; "
-                "stream clients run plain SGD — use the synchronous regime"
-            )
-        rule = cfg.algorithm
-        if rule not in aggregators.FLAT_CAPABLE or rule in aggregators.NEEDS_REFERENCE:
-            raise ValueError(f"unknown stream algorithm {cfg.algorithm}")
-        delta_flat = aggregators.FLAT_AGGREGATORS[rule](
-            g,
-            **aggregators.rule_kwargs(
-                rule, n_byzantine=cfg.n_byzantine_hint, geomed_iters=cfg.geomed_iters
-            ),
-        )
-        params = pt.tree_add(params, flat_mod.unflatten_tree(delta_flat, spec))
-        metrics["delta_norm"] = jnp.linalg.norm(delta_flat)
 
-    if use_trust:
-        metrics["trust_weight_mean"] = jnp.mean(weights)
-        metrics["quarantined"] = jnp.sum(new_trust.quarantined.astype(jnp.int32))
-    if update_norms is None:
-        update_norms = jnp.linalg.norm(g, axis=1)
-    metrics["update_norm_mean"] = jnp.mean(update_norms)
-    if cfg.telemetry:
-        metrics["obs"] = obs_metrics.flush_bundle(
-            rnd=rnd, fill=buf.count, capacity=buf_mod.capacity_of(buf),
-            drops=buf.drops, taus=taus, discounts=discounts,
-            stats=stats_obs, update_norms=update_norms, reputations=weights,
-            trust_state=new_trust if use_trust else None,
-            c=cfg.c if cfg.algorithm == "drag" else cfg.c_br,
-            mode=cfg.algorithm if cfg.algorithm in ("drag", "br_drag") else "none",
-        )
-        if cfg.monitor is not None:
-            # detectors read ONLY the already-reduced bundle; their O(1)
-            # state rides the metrics dict back to the host loop
-            mstate = (
-                monitor_state if monitor_state != () else obs_monitor.monitor_init()
-            )
-            metrics["obs_monitor"] = obs_monitor.monitor_step(
-                mstate, metrics["obs"], cfg.monitor
-            )
-    return params, new_drag, rnd + 1, buf_mod.reset(buf), new_adv, new_trust, metrics
-
-
-#: stream algorithms with a hierarchical (one-psum) sharded flush —
-#: per-row blend coefficients are pod-local for these, so the cross-pod
-#: traffic is exactly the partial [d] sums
-SHARDABLE = ("fedavg", "drag", "br_drag")
-
-
-def _flush_sharded(
-    loss_fn: Callable,
-    cfg: StreamConfig,
-    params: pt.Pytree,
-    drag_state: drag.DragState,
-    rnd: jax.Array,
-    buf: sharded_mod.ShardedBufferState,
-    key,
-    root_batches=None,
-    adv_state: pt.Pytree = (),
-    trust_state: pt.Pytree = (),
-    reference=None,
-    mesh=None,
-    monitor_state: pt.Pytree = (),
-):
-    """:func:`flush` on the sharded plane (``repro.stream.sharded``).
-
-    Same contract and return signature; the aggregation core is the
-    hierarchical per-pod two-pass flush whose partials meet in one psum.
-    Rows are in POD-MAJOR order (the row order of the sharded plane);
-    at p = 1 that is arrival order and the whole flush is bit-for-bit
-    the single-buffer flush.  Adversary crafting and the trust update
-    run on the replicated [K]-sized quantities / the [K, d] pod-major
-    view OUTSIDE the manual region — the serving reduction itself stays
-    one psum.
-    """
-    p, kp, d = buf.slots.shape
-    k = p * kp
-    spec = flat_mod.spec_of(params)
-    taus2 = sharded_mod.staleness(buf, rnd)  # [p, K/p], replicated metadata
-    discounts2 = stale.make_discount(cfg.discount, cfg.discount_a)(taus2)
-    taus, discounts = taus2.reshape(k), discounts2.reshape(k)
-    client_ids = buf.client_ids.reshape(k)
-
-    adv = adversary_engine.resolve(cfg.attack, dict(cfg.attack_kw))
-    if jax.tree.structure(adv_state) != jax.tree.structure(adv.init()):
-        raise ValueError(
-            f"attack {cfg.attack!r} carries state; build the stream state "
-            "with init_stream_state(params, capacity, cfg)"
-        )
-    ctx = adversary_engine.AttackContext(
-        key=key, updates=buf.slots.reshape(k, d),
-        malicious_mask=buf.malicious.reshape(k), round=rnd,
-        taus=taus, discounts=discounts, spec=spec,
+    step = fl_aggregate.server_step(
+        cfg, params, drag_state, adv_state, trust_state, monitor_state,
+        stack, malicious, rnd, key, reference=r_flat,
+        discounts=discounts, facts=facts, reduce=reduce,
     )
-    g, new_adv = adv.craft(adv_state, ctx)
-    slots3 = g.reshape(p, kp, d)
-
-    use_trust = cfg.trust and cfg.algorithm in ("drag", "br_drag")
-    if cfg.trust and not use_trust:
-        raise ValueError(
-            f"trust reputation needs a reference direction; stream algorithm "
-            f"{cfg.algorithm!r} has none (use drag or br_drag)"
-        )
-    if use_trust and not isinstance(trust_state, trust_mod.TrustState):
-        raise ValueError(
-            "cfg.trust=True needs a trust table; build the stream state "
-            "with init_stream_state(params, capacity, cfg, n_clients)"
-        )
-    tcfg = trust_mod.TrustConfig(**dict(cfg.trust_kw)) if use_trust else None
-    weights = (
-        trust_mod.reputation(trust_state, client_ids, tcfg) if use_trust else None
-    )
-
-    metrics: dict = {
-        "staleness_mean": jnp.mean(taus.astype(jnp.float32)),
-        "staleness_max": jnp.max(taus),
-        "discount_mean": jnp.mean(discounts),
-    }
-    new_drag = drag_state
-    new_trust = trust_state
-
-    if cfg.algorithm == "drag":
-        params, new_drag, dm, stats = sharded_mod.drag_round_step(
-            params, drag_state, slots3, alpha=cfg.alpha, c=cfg.c,
-            discounts2=discounts2, weights=weights, mesh=mesh,
-        )
-        metrics.update(dm)
-        if use_trust:
-            div, nr = trust_mod.signals_from_stats(*stats)
-            new_trust = trust_mod.observe(
-                trust_state, client_ids, div, nr, tcfg,
-                gate=drag_state.initialized,
-            )
-    elif cfg.algorithm == "br_drag":
-        if reference is None:
-            assert root_batches is not None, "br_drag needs a root dataset"
-            grad_fn = jax.grad(loss_fn)
-            reference = br_drag.root_reference(
-                params, lambda p_, b: grad_fn(p_, b), root_batches, cfg.lr
-            )
-        r_flat = flat_mod.flatten_tree(reference)
-        params, dm, stats = sharded_mod.br_drag_round_step(
-            params, slots3, r_flat, c=cfg.c_br, discounts2=discounts2,
-            weights=weights, mesh=mesh,
-        )
-        metrics.update(dm)
-        if use_trust:
-            div, nr = trust_mod.signals_from_stats(*stats)
-            new_trust = trust_mod.observe(trust_state, client_ids, div, nr, tcfg)
-    elif cfg.algorithm == "fedavg":
-        delta_flat, stats = sharded_mod.mean_flush(slots3, mesh=mesh)
-        params = pt.tree_add(params, flat_mod.unflatten_tree(delta_flat, spec))
-        metrics["delta_norm"] = jnp.linalg.norm(delta_flat)
-    else:
-        raise ValueError(
-            f"stream algorithm {cfg.algorithm!r} has no hierarchical sharded "
-            f"flush (shardable: {SHARDABLE}); use shards=0"
-        )
-
-    if use_trust:
-        metrics["trust_weight_mean"] = jnp.mean(weights)
-        metrics["quarantined"] = jnp.sum(new_trust.quarantined.astype(jnp.int32))
-    metrics["update_norm_mean"] = jnp.mean(jnp.sqrt(stats[1]))
-    if cfg.telemetry:
-        metrics["obs"] = obs_metrics.flush_bundle(
-            rnd=rnd, fill=sharded_mod.total_count(buf), capacity=k,
-            drops=buf.drops, pod_fill=buf.counts, taus=taus,
-            discounts=discounts,
-            stats=stats if cfg.algorithm in ("drag", "br_drag") else None,
-            update_norms=jnp.sqrt(stats[1]), reputations=weights,
-            trust_state=new_trust if use_trust else None,
-            c=cfg.c if cfg.algorithm == "drag" else cfg.c_br,
-            mode=cfg.algorithm if cfg.algorithm in ("drag", "br_drag") else "none",
-        )
-        if cfg.monitor is not None:
-            mstate = (
-                monitor_state if monitor_state != () else obs_monitor.monitor_init()
-            )
-            metrics["obs_monitor"] = obs_monitor.monitor_step(
-                mstate, metrics["obs"], cfg.monitor
-            )
-    return (
-        params, new_drag, rnd + 1, sharded_mod.reset(buf), new_adv, new_trust,
-        metrics,
-    )
+    metrics = step.metrics
+    if step.monitor is not None:
+        # the monitor's O(1) state rides the metrics dict back to the host loop
+        metrics["obs_monitor"] = step.monitor
+    return (step.params, step.drag, rnd + 1, reset(buf), step.adversary, step.trust,
+            metrics)
 
 
 def make_flush_fn(loss_fn: Callable, cfg: StreamConfig, with_root: bool, mesh=None):

@@ -1,47 +1,39 @@
-"""Mesh-sharded ingest buffer with a hierarchical one-psum flush.
+"""Mesh-sharded ingest buffer and the sharded plane's one-psum reduce.
 
 The single-device buffer (``repro.stream.buffer``) is one ``[K, d]``
 slot matrix; this module splits it into per-pod ``[K/p, d]`` sub-buffers
 laid out with a plain ``NamedSharding`` over a mesh axis (rows = clients
 shard over the pod axis; metadata stays replicated — it is O(K), not
-O(K·d)).  This is what lets the async stream engine ride
-``launch.train``'s SPMD round: each pod ingests its own clients and runs
-the fused flush (``kernels.ops.calibrated_reduce`` — one ``fused_flush``
-pass for VMEM-resident sub-stacks, else ``dot_norms`` +
-``blend_reduce``) over ITS rows only.
+O(K·d)).
 
 Routing: ``client_id`` hash-routes to a home pod (:func:`route_pod`),
 falling back to the least-full pod when the home sub-buffer is full —
 so an upload is dropped only when the WHOLE buffer is full, exactly the
 single-buffer acceptance behaviour.
 
-The hierarchical flush keeps DRAG/BR-DRAG's O(d) communication story at
-pod scale.  Everything cross-pod is ONE ``psum``:
-
-  * per-row blend coefficients need only that row's ``<g, r>`` /
-    ``||g||²`` plus ``||r||²`` — and r is replicated, so every
-    coefficient is pod-local;
-  * the aggregation weights (staleness discounts × trust reputations)
-    are computed REPLICATED from the replicated metadata and normalised
-    globally before the blend — no collective;
-  * each pod's flush emits a partial ``[d]`` weighted sum;
-    the partials — together with the per-row DoD/trust scalars,
-    scattered into their ``[p, K/p]`` slots — meet in exactly one
-    ``psum`` (:func:`psum_bundle`, the probe point counted by
-    ``kernels.instrument``) before the egress unflatten.
+A sharded flush is the server step every flush runs
+(``repro.fl.aggregate.server_step``): :func:`as_stack` views the
+sub-buffers as one pod-major ``[K, d]`` stack for the adversary, the
+trust layer and the metrics, and DRAG, BR-DRAG and the fedavg mean
+reduce through :func:`pod_reduce`.  Everything cross-pod is ONE
+``psum``: per-row blend coefficients need only that row's ``<g, r>`` /
+``||g||²`` plus ``||r||²`` of the replicated r; the aggregation weights
+(staleness discounts × trust reputations) come from the replicated
+metadata, normalised once over all K rows; so each pod's flush
+(``kernels.ops.calibrated_reduce`` over ITS rows) emits a partial
+``[d]`` sum, and the partials — with the per-row DoD/trust scalars
+scattered into their ``[p, K/p]`` slots — meet in one
+:func:`psum_bundle` (the probe point ``kernels.instrument`` counts).
 
 With ``mesh=None`` the same per-pod program runs as an unrolled loop on
-one device (the emulation path — benchmarks and single-process tests);
-the cross-pod reduction still goes through the one :func:`psum_bundle`
-call, so the program structure is identical.  At ``p = 1`` the flush is
-bit-for-bit the single-buffer flush (same kernels, same block sizes,
+one device (the emulation path — benchmarks and single-process tests),
+still through the one :func:`psum_bundle` call.  At ``p = 1`` the flush
+is bit-for-bit the single-buffer flush (same kernels, same block sizes,
 same operation order) — pinned by ``tests/test_sharded_buffer.py``.
-
-The single-buffer path stays the numerical oracle, the same way
-``tests/test_flat.py`` pins flat vs pytree.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -49,7 +41,6 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import drag
 from repro.core import flat as flat_mod
 from repro.core import pytree as pt
 from repro.kernels import ops as kops
@@ -241,6 +232,19 @@ def staleness(buf: ShardedBufferState, server_round) -> jax.Array:
     )
 
 
+def as_stack(buf: ShardedBufferState, spec: flat_mod.StackSpec,
+             server_round) -> flat_mod.UpdateStack:
+    """View the sub-buffers as one POD-MAJOR ``[K, d]``
+    :class:`~repro.core.flat.UpdateStack` — at p = 1 arrival order."""
+    p, kp, d = buf.slots.shape
+    return flat_mod.UpdateStack(
+        data=buf.slots.reshape(p * kp, d),
+        client_ids=buf.client_ids.reshape(p * kp),
+        staleness=staleness(buf, server_round).reshape(p * kp),
+        spec=spec,
+    )
+
+
 def make_ingest_fn():
     """Jitted donated ingest: the buffer argument is consumed in place."""
     return jax.jit(ingest, donate_argnums=(0,))
@@ -270,25 +274,81 @@ def psum_bundle(bundle: pt.Pytree, axis_name: str | None):
     )
 
 
-def _pod_passes(g_local, r_flat, w_local, disc_local, *, mode, c, init,
-                k_total, interpret):
-    """One pod's share of the flush: the SAME fused flush the
-    single-buffer plane runs (``kops.calibrated_reduce`` — single-pass
-    when the local stack is VMEM-resident, two streaming passes
-    otherwise), over the local ``[K/p, d]`` rows only.
+def pod_reduce(pods: int, mesh=None, pod_axis: str = POD_AXIS):
+    """The sharded plane's calibrated reduce: ``kops.calibrated_reduce``'s
+    signature over POD-MAJOR ``[K, d]`` rows, run as p per-pod flushes
+    whose partials meet in one psum.
 
-    Returns (partial delta [d], dots [K/p], g_sq [K/p], lam [K/p],
-    r_sq []).  The partial delta carries the globally-normalised weights
-    already multiplied in, so partials sum directly.  The bootstrap
-    fallback (eq. 5a) is uniform 1/K over the GLOBAL worker count.
+    Each pod runs the SAME fused flush the single-buffer plane runs
+    (``kops.calibrated_reduce`` — single-pass when its ``[K/p, d]`` rows
+    are VMEM-resident, two streaming passes otherwise) over its rows
+    only.  ``w`` arrives normalised over all K rows, so the partial
+    ``[d]`` sums add directly; per-row blend coefficients need only that
+    row's ``<g, r>`` / ``||g||²`` plus ``||r||²`` of the replicated r, so
+    every coefficient is pod-local.  With ``mesh=None`` the pods run as
+    an unrolled loop on one device and still meet in one
+    :func:`psum_bundle`.  Returns (delta [d], lam [K], (dots [K],
+    g_sq [K], r_sq [])), the per-row vectors in pod-major order.
     """
-    kp = g_local.shape[0]
-    partial, lam, (dots, gsq, rsq) = kops.calibrated_reduce(
-        g_local, r_flat, c, mode, w=w_local, discounts=disc_local,
-        init=init, boot_aw=jnp.full((kp,), 1.0 / k_total, jnp.float32),
-        interpret=interpret,
-    )
-    return partial, dots, gsq, lam, rsq
+
+    def reduce(g, r, c, mode, *, w, discounts=None, init=None, boot_aw=None,
+               interpret=None):
+        k, d = g.shape
+        kp = k // pods
+        slots3 = g.reshape(pods, kp, d)
+
+        def per_pod(x, fill):
+            x = jnp.full((k,), fill, jnp.float32) if x is None else x
+            return jnp.asarray(x, jnp.float32).reshape(pods, kp)
+
+        w2, disc2, boot2 = per_pod(w, 0.0), per_pod(discounts, 1.0), per_pod(boot_aw, 0.0)
+        pod_pass = partial(kops.calibrated_reduce, c=c, mode=mode, interpret=interpret)
+
+        if mesh is None:
+            parts = [pod_pass(slots3[i], r, w=w2[i], discounts=disc2[i], init=init,
+                              boot_aw=boot2[i]) for i in range(pods)]
+            delta = psum_bundle({"delta": jnp.stack([pr[0] for pr in parts])}, None)["delta"]
+            lam = jnp.stack([pr[1] for pr in parts])
+            dots, gsq = (jnp.stack([pr[2][j] for pr in parts]) for j in (0, 1))
+            rsq = parts[0][2][2]
+        else:
+            if mesh.shape[pod_axis] != pods:
+                raise ValueError(
+                    f"mesh axis {pod_axis!r} size {mesh.shape[pod_axis]} != {pods} pods"
+                )
+
+            def body(g_block, r_rep, w_block, disc_block, boot_block, init_rep):
+                i = jax.lax.axis_index(pod_axis)
+                partial_l, lam_l, (dots_l, gsq_l, rsq_l) = pod_pass(
+                    g_block[0], r_rep, w=w_block[0], discounts=disc_block[0],
+                    init=None if init is None else init_rep, boot_aw=boot_block[0],
+                )
+                # scatter this pod's per-row scalars into their [p, K/p]
+                # slots so they ride the ONE psum alongside the [d] partial
+                scat = lambda x: jnp.zeros((pods,) + x.shape, x.dtype).at[i].set(x)  # noqa: E731
+                red = psum_bundle(
+                    {"delta": partial_l, "dots": scat(dots_l),
+                     "gsq": scat(gsq_l), "lam": scat(lam_l)},
+                    pod_axis,
+                )
+                # r is replicated, so r_sq is already identical on every pod
+                return red["delta"], red["dots"], red["gsq"], red["lam"], rsq_l
+
+            rows = P(pod_axis, None)
+            fn = jax.shard_map(
+                body,
+                mesh=mesh,
+                axis_names={pod_axis},
+                in_specs=(P(pod_axis, None, None), P(), rows, rows, rows, P()),
+                out_specs=(P(), P(), P(), P(), P()),
+                check_vma=False,
+            )
+            init_arg = jnp.asarray(False) if init is None else jnp.asarray(init)
+            delta, dots, gsq, lam, rsq = fn(slots3, r, w2, disc2, boot2, init_arg)
+
+        return delta, lam.reshape(k), (dots.reshape(k), gsq.reshape(k), rsq)
+
+    return reduce
 
 
 def hierarchical_flush(
@@ -304,169 +364,21 @@ def hierarchical_flush(
     pod_axis: str = POD_AXIS,
     interpret: bool | None = None,
 ):
-    """The sharded DRAG/BR-DRAG reduction: per-pod fused passes, one psum.
+    """The sharded DRAG/BR-DRAG reduction over ``[p, K/p, d]`` sub-buffers:
+    :func:`pod_reduce` with the raw weights normalised over all K rows
+    (collective-free: they derive from replicated metadata) and the
+    bootstrap fallback (eq. 5a) uniform 1/K over the GLOBAL row count.
 
     Returns (delta [d], lam [K], (dots [K], g_sq [K], r_sq [])) with the
     per-row vectors in pod-major order — the row order of the sharded
     plane.  The stats feed ``trust.signals_from_stats`` exactly as on the
     single-buffer path.
     """
-    p, kp, _ = slots3.shape
+    p, kp, d = slots3.shape
     k = p * kp
-    disc2 = (
-        jnp.ones((p, kp), jnp.float32) if discounts2 is None
-        else jnp.asarray(discounts2, jnp.float32)
+    return pod_reduce(p, mesh, pod_axis)(
+        slots3.reshape(k, d), r_flat, c, mode,
+        w=kops.normalize_weights(weights, k),
+        discounts=None if discounts2 is None else jnp.reshape(discounts2, (k,)),
+        init=init, boot_aw=jnp.full((k,), 1.0 / k, jnp.float32), interpret=interpret,
     )
-    # weight normalisation is GLOBAL but collective-free: weights derive
-    # from replicated metadata (staleness tags, trust table), so every
-    # pod computes the identical normalised [p, K/p] table
-    w2 = kops.normalize_weights(weights, k).reshape(p, kp)
-
-    if mesh is None:
-        parts = [
-            _pod_passes(
-                slots3[i], r_flat, w2[i], disc2[i],
-                mode=mode, c=c, init=init, k_total=k, interpret=interpret,
-            )
-            for i in range(p)
-        ]
-        bundle = {"delta": jnp.stack([pr[0] for pr in parts])}
-        delta = psum_bundle(bundle, None)["delta"]
-        dots = jnp.stack([pr[1] for pr in parts])
-        gsq = jnp.stack([pr[2] for pr in parts])
-        lam = jnp.stack([pr[3] for pr in parts])
-        rsq = parts[0][4]
-    else:
-        if mesh.shape[pod_axis] != p:
-            raise ValueError(
-                f"mesh axis {pod_axis!r} size {mesh.shape[pod_axis]} != {p} pods"
-            )
-
-        def body(g_block, r_rep, w_block, disc_block, init_rep):
-            i = jax.lax.axis_index(pod_axis)
-            partial, dots_l, gsq_l, lam_l, rsq_l = _pod_passes(
-                g_block[0], r_rep, w_block[0], disc_block[0],
-                mode=mode, c=c,
-                init=None if init is None else init_rep,
-                k_total=k, interpret=interpret,
-            )
-            # scatter this pod's per-row scalars into their [p, K/p]
-            # slots so they ride the ONE psum alongside the [d] partial
-            scat = lambda x: jnp.zeros((p,) + x.shape, x.dtype).at[i].set(x)  # noqa: E731
-            red = psum_bundle(
-                {"delta": partial, "dots": scat(dots_l),
-                 "gsq": scat(gsq_l), "lam": scat(lam_l)},
-                pod_axis,
-            )
-            # r is replicated, so r_sq is already identical on every pod
-            return red["delta"], red["dots"], red["gsq"], red["lam"], rsq_l
-
-        fn = jax.shard_map(
-            body,
-            mesh=mesh,
-            axis_names={pod_axis},
-            in_specs=(P(pod_axis, None, None), P(), P(pod_axis, None),
-                      P(pod_axis, None), P()),
-            out_specs=(P(), P(), P(), P(), P()),
-            check_vma=False,
-        )
-        init_arg = jnp.asarray(False) if init is None else jnp.asarray(init)
-        delta, dots, gsq, lam, rsq = fn(slots3, r_flat, w2, disc2, init_arg)
-
-    return delta, lam.reshape(k), (dots.reshape(k), gsq.reshape(k), rsq)
-
-
-# --------------------------------------------------- algorithm entry points
-
-def drag_round_step(
-    params: pt.Pytree,
-    state: drag.DragState,
-    slots3: jax.Array,
-    *,
-    alpha: float,
-    c: float,
-    discounts2=None,
-    weights=None,
-    mesh=None,
-    pod_axis: str = POD_AXIS,
-    interpret: bool | None = None,
-):
-    """``drag.round_step_flat`` on the sharded plane.
-
-    Identical semantics and — at p = 1 — identical operations: the same
-    ``kops.calibrated_reduce`` flush (same ``flush_path`` selection,
-    same kernels, same operation order) over the same ``[K, d]`` rows,
-    so the single-pod flush is bit-for-bit the single-buffer flush.
-
-    Returns (params', state', metrics, (dots, g_sq, r_sq)).
-    """
-    spec = flat_mod.spec_of(params)
-    r_flat = flat_mod.flatten_tree(state.reference)
-    delta_flat, lam, stats = hierarchical_flush(
-        slots3, r_flat, mode="drag", c=c, discounts2=discounts2,
-        weights=weights, init=state.initialized, mesh=mesh,
-        pod_axis=pod_axis, interpret=interpret,
-    )
-    ema = (1.0 - alpha) * r_flat + alpha * delta_flat
-    new_ref_flat = jnp.where(state.initialized, ema, delta_flat)
-    new_params = pt.tree_add(params, flat_mod.unflatten_tree(delta_flat, spec))
-    new_state = drag.DragState(
-        reference=flat_mod.unflatten_tree(new_ref_flat, spec),
-        initialized=jnp.asarray(True),
-    )
-    metrics = {
-        "dod_mean": jnp.mean(lam),
-        "dod_max": jnp.max(lam),
-        "delta_norm": jnp.linalg.norm(delta_flat),
-        "ref_norm": jnp.linalg.norm(new_ref_flat),
-    }
-    return new_params, new_state, metrics, stats
-
-
-def br_drag_round_step(
-    params: pt.Pytree,
-    slots3: jax.Array,
-    reference_flat: jax.Array,
-    *,
-    c: float,
-    discounts2=None,
-    weights=None,
-    mesh=None,
-    pod_axis: str = POD_AXIS,
-    interpret: bool | None = None,
-):
-    """``br_drag.round_step_flat`` on the sharded plane.
-
-    Returns (params', metrics, (dots, g_sq, r_sq))."""
-    spec = flat_mod.spec_of(params)
-    delta_flat, lam, stats = hierarchical_flush(
-        slots3, reference_flat, mode="br_drag", c=c, discounts2=discounts2,
-        weights=weights, mesh=mesh, pod_axis=pod_axis, interpret=interpret,
-    )
-    new_params = pt.tree_add(params, flat_mod.unflatten_tree(delta_flat, spec))
-    metrics = {
-        "dod_mean": jnp.mean(lam),
-        "dod_max": jnp.max(lam),
-        "delta_norm": jnp.linalg.norm(delta_flat),
-        "ref_norm": jnp.linalg.norm(reference_flat),
-    }
-    return new_params, metrics, stats
-
-
-def mean_flush(
-    slots3: jax.Array,
-    *,
-    weights=None,
-    mesh=None,
-    pod_axis: str = POD_AXIS,
-    interpret: bool | None = None,
-):
-    """Hierarchical (weighted) mean — the FedAvg flush on the sharded
-    plane.  Returns (delta [d], (dots, g_sq, r_sq)); g_sq gives the
-    per-row update norms for free."""
-    r0 = jnp.zeros((slots3.shape[2],), jnp.float32)
-    delta, _, stats = hierarchical_flush(
-        slots3, r0, mode="mean", weights=weights, mesh=mesh,
-        pod_axis=pod_axis, interpret=interpret,
-    )
-    return delta, stats

@@ -62,10 +62,10 @@ def make_discount(name: str, a: float = 0.5):
 # async argument order.  With discounts = 1 they match the synchronous
 # calls bit-for-bit.
 #
-# These are the PYTREE-ORACLE forms.  The serving flush
-# (``repro.stream.server.flush``) runs the flat update plane instead:
-# ``drag.round_step_flat`` / ``br_drag.round_step_flat`` fold phi(tau)
-# and the trust weights into the fused two-pass kernels.
+# These are the PYTREE-ORACLE forms.  Every serving flush runs the flat
+# update plane instead: ``repro.fl.aggregate.server_step`` hands phi(tau)
+# and the trust weights to ``drag.round_step_flat`` /
+# ``br_drag.round_step_flat``, which fold them into the flush kernels.
 
 
 def drag_aggregate(

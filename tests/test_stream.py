@@ -316,12 +316,14 @@ def _mlp_setup(n_workers=12, mal=0.0, attack="none"):
 
 
 class TestBridgeEquivalence:
-    # tier-1 keeps one algorithm (drag — the richest path: calibration +
-    # bootstrap + reference EMA); the other two ride the weekly slow tier
+    # tier-1 keeps drag (the richest path: calibration + bootstrap +
+    # reference EMA) and fltrust (a root reference outside the DRAG
+    # bodies); fedavg and br_drag ride the weekly slow tier
     @pytest.mark.parametrize("alg", [
         pytest.param("fedavg", marks=pytest.mark.slow),
         "drag",
         pytest.param("br_drag", marks=pytest.mark.slow),
+        "fltrust",
     ])
     def test_bit_for_bit_vs_federated_round(self, alg):
         """ISSUE acceptance: capacity-S, zero-latency, phi=none stream ==
@@ -330,7 +332,7 @@ class TestBridgeEquivalence:
         from repro.fl.round import RoundConfig, federated_round, init_server_state
 
         data, params, loss_fn = _mlp_setup()
-        with_root = alg == "br_drag"
+        with_root = alg in ("br_drag", "fltrust")
         cfg = RoundConfig(algorithm=alg, local_steps=2, lr=0.05)
         s_sync = init_server_state(params, 12)
         s_str = init_server_state(params, 12)
